@@ -44,6 +44,7 @@ import numpy as np  # noqa: E402
 import jax  # noqa: E402
 
 from repro.partition import DATA, MODEL, MeshPlan  # noqa: E402
+from repro.runtime.compile_cache import use_compile_cache  # noqa: E402
 from repro.serve.engine import (EngineConfig, SamplingParams,  # noqa: E402
                                 build_engine, generate)
 from repro.serve.service import (AdmissionRejected,  # noqa: E402
@@ -325,8 +326,10 @@ def run_failover(report, *, requests=12, kills=2, seed=0, config=None,
     rng = np.random.default_rng(seed)
     prompts, n_toks = _workload(rng, cfg.vocab_size, requests)
     sampling = [SamplingParams(max_tokens=n) for n in n_toks]
-    expect = generate(spec.build(), prompts, sampling)
-    total_expected = sum(len(e.tokens) for e in expect)
+    # no eos: every request runs to max_tokens.  The uninterrupted
+    # reference runs only after the supervisor's workers have exited, so
+    # this process stays off the devices while they own them.
+    total_expected = sum(n_toks)
     thresholds = [total_expected * (i + 1) // (kills + 1)
                   for i in range(kills)]
 
@@ -365,6 +368,7 @@ def run_failover(report, *, requests=12, kills=2, seed=0, config=None,
                     snap, sup.n_failovers)
 
     streamed, comps, snap, n_failovers = asyncio.run(drive())
+    expect = generate(spec.build(), prompts, sampling)
 
     lost = dup = 0
     completed = 0
@@ -472,6 +476,7 @@ def main():
                          "service metrics snapshot's speculation counters "
                          "(--rate makes it a trajectory-free smoke)")
     args = ap.parse_args()
+    use_compile_cache()
     print("name,value,derived")
 
     def report(name, value, derived=""):

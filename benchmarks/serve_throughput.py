@@ -21,11 +21,10 @@ Standalone:
 
 ``--kernel-backend`` selects the step-kernel implementation (jnp
 materialized-gather reference vs the fused Pallas paged-attention path);
-running the bench once per backend appends PAIRED trajectory entries.  On
-CPU hosts the pallas path runs in interpret mode and is EXPECTED to be
-slower — there the pairing is a parity/ABI record, not a speedup claim;
-the bytes the fused path eliminates are priced structurally in
-``kernel_bench.py`` and the wall-clock win realizes on TPU.
+running the bench once per backend appends PAIRED trajectory entries.
+``pallas`` compiles the kernels for the TPU and refuses any other backend;
+on CPU hosts pass ``pallas-interpret``, whose pairing is a parity/ABI
+record, not a speed: the interpreter is expected to be slower.
 
 ``--config`` serves a reduced registry architecture instead of the built-in
 dense bench model — including SSM/hybrid families, which exercise the dense
@@ -55,6 +54,7 @@ import jax.numpy as jnp  # noqa: E402
 
 from repro.models.config import ModelConfig  # noqa: E402
 from repro.partition import DATA, MODEL, MeshPlan  # noqa: E402
+from repro.runtime.compile_cache import use_compile_cache  # noqa: E402
 from repro.serve.engine import (EngineConfig, EngineStats,  # noqa: E402
                                 SamplingParams, build_engine, generate)
 
@@ -750,6 +750,7 @@ def main():
     ap.add_argument("--prefix-requests", type=int, default=8,
                     help="workload size for --prefix-workload")
     args = ap.parse_args()
+    use_compile_cache()
     print("name,value,derived")
 
     def report(name, value, derived=""):

@@ -1,9 +1,1 @@
-"""repro — OpenCL + OpenSHMEM hybrid programming model reproduction in JAX.
-
-Importing the package installs small jax compatibility shims (see
-``repro._compat``) so the codebase runs unmodified on the pinned toolchain.
-"""
-
-from repro import _compat as _compat
-
-_compat.install()
+"""repro — OpenCL + OpenSHMEM hybrid programming model reproduction in JAX."""

@@ -7,9 +7,11 @@ One vocabulary everywhere (``EngineConfig.kernel_backend``, the decode /
 prefill bodies, the SSD scan call sites):
 
   * ``"jnp"``              — pure-jnp paths (the bit-exact reference).
-  * ``"pallas"``           — Pallas kernels; interpret mode is picked
-    automatically off-TPU so the same config runs on CPU runners.
-  * ``"pallas-interpret"`` — Pallas kernels, interpreter forced (CI).
+  * ``"pallas"``           — compiled Pallas kernels; TPU only.  Off the
+    TPU it raises rather than fall back, so a run that lost its chip
+    cannot pass on the interpreter.
+  * ``"pallas-interpret"`` — the same kernels through the Pallas
+    interpreter (CPU runners, CI).
 """
 
 from __future__ import annotations
@@ -43,5 +45,9 @@ def resolve_kernel_backend(backend: str) -> Tuple[bool, bool]:
     check_kernel_backend(backend)
     if backend == "jnp":
         return False, False
-    return True, backend == "pallas-interpret" \
-        or jax.default_backend() != "tpu"
+    if backend == "pallas" and jax.default_backend() != "tpu":
+        raise ValueError(
+            f"kernel_backend 'pallas' compiles its kernels for the TPU, but "
+            f"the default backend is {jax.default_backend()!r}; use "
+            f"'pallas-interpret' to run them through the interpreter")
+    return True, backend == "pallas-interpret"

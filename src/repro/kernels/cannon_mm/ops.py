@@ -1,9 +1,8 @@
 """Jit'd public wrapper for the cannon_mm Pallas kernel.
 
-``blocked_matmul`` dispatches to the Pallas kernel on TPU and transparently
-falls back to interpret mode elsewhere (this container is CPU-only; interpret
-mode executes the kernel body in Python, validating BlockSpec indexing and
-numerics against the same code path the TPU would run).
+``blocked_matmul`` compiles the Pallas kernel for the TPU; ``interpret=True``
+runs the same kernel body through the Pallas interpreter instead, which
+validates BlockSpec indexing and numerics on hosts without a chip.
 """
 
 from __future__ import annotations
@@ -18,16 +17,12 @@ from repro.kernels.cannon_mm.kernel import matmul_pallas
 from repro.kernels.cannon_mm.ref import matmul_ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",
-                                             "out_dtype", "force_interpret"))
+                                             "out_dtype", "interpret"))
 def blocked_matmul(a: jax.Array, b: jax.Array, *, block_m: int = 256,
                    block_n: int = 256, block_k: int = 256,
                    out_dtype: Optional[jnp.dtype] = None,
-                   force_interpret: bool = False) -> jax.Array:
+                   interpret: bool = False) -> jax.Array:
     M, K = a.shape
     _, N = b.shape
     bm, bn, bk = min(block_m, M), min(block_n, N), min(block_k, K)
@@ -37,4 +32,4 @@ def blocked_matmul(a: jax.Array, b: jax.Array, *, block_m: int = 256,
         return matmul_ref(a, b, out_dtype)
     return matmul_pallas(a, b, block_m=bm, block_n=bn, block_k=bk,
                          out_dtype=out_dtype,
-                         interpret=force_interpret or not _on_tpu())
+                         interpret=interpret)

@@ -1,4 +1,5 @@
-"""Jit'd public wrapper for the flash_attention Pallas kernel."""
+"""Jit'd public wrapper for the flash_attention Pallas kernel (compiled for
+the TPU; ``interpret=True`` runs it through the Pallas interpreter)."""
 
 from __future__ import annotations
 
@@ -12,17 +13,13 @@ from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.flash_attention.ref import attention_ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "q_offset", "block_q", "block_kv", "scale", "force_interpret",
+    "causal", "q_offset", "block_q", "block_kv", "scale", "interpret",
     "force_ref"))
 def flash_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
                     block_q: int = 128, block_kv: int = 128,
                     scale: Optional[float] = None,
-                    force_interpret: bool = False,
+                    interpret: bool = False,
                     force_ref: bool = False) -> jax.Array:
     Sq, Skv = q.shape[2], k.shape[2]
     bq, bk = min(block_q, Sq), min(block_kv, Skv)
@@ -31,4 +28,4 @@ def flash_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
                              scale=scale)
     return flash_attention_pallas(
         q, k, v, causal=causal, q_offset=q_offset, block_q=bq, block_kv=bk,
-        scale=scale, interpret=force_interpret or not _on_tpu())
+        scale=scale, interpret=interpret)

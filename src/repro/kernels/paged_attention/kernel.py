@@ -72,7 +72,7 @@ def _paged_flash_kernel(lidx_ref, own_ref, q_ref, k_ref, v_ref, qpos_ref,
         # which physical page backs it — the causal mask runs on the LABELS
         kv_pos = t * stride + jax.lax.broadcasted_iota(
             jnp.int32, (L, stride), 1)
-        mask = qpos_ref[0][:, None] >= kv_pos
+        mask = qpos_ref[0, 0][:, None] >= kv_pos
         s = jnp.where(mask[None], s, NEG_INF)
         m_prev = m_s[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
@@ -128,7 +128,10 @@ def paged_attention_pallas(
                          lambda b, t, lidx, own: (lidx[b, t], 0, 0, 0)),
             pl.BlockSpec((1, stride, kvh, hd),
                          lambda b, t, lidx, own: (lidx[b, t], 0, 0, 0)),
-            pl.BlockSpec((1, L), lambda b, t, lidx, own: (b, 0)),
+            # (B, 1, L): a (1, L) block of a 2-D (B, L) array breaks the
+            # TPU rule that a block's last two dims tile by (8, 128) or
+            # span the array
+            pl.BlockSpec((1, 1, L), lambda b, t, lidx, own: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, Hq, L), lambda b, t, lidx, own: (b, 0, 0)),
@@ -152,4 +155,4 @@ def paged_attention_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(lidx, own, q, kc, vc, q_pos)
+    )(lidx, own, q, kc, vc, q_pos[:, None, :])

@@ -5,8 +5,8 @@
     gathered ``(B, T * stride, kvh, hd)`` K/V copy alive per call.
   * ``backend="pallas"``  — the fused kernel (kernel.py): the block table
     rides as a scalar-prefetch operand and every page is DMA'd from the
-    arena exactly once, in place; ``interpret=True`` runs the same kernel
-    through the Pallas interpreter (CPU CI).
+    arena exactly once, in place; compiled for the TPU, or run through the
+    Pallas interpreter with ``interpret=True`` (CPU CI).
 
 Both return **LSE partials** ``(m, l, acc)`` over this grid row's pages, so
 the SHMEM row-merge (``combine_partials``) downstream is backend-blind.
@@ -55,7 +55,7 @@ def paged_attention(q: jax.Array, kc: jax.Array, vc: jax.Array,
                     table: jax.Array, q_pos: jax.Array, *, stride: int,
                     row, qrows: int, scale: Optional[float] = None,
                     backend: str = "jnp",
-                    interpret: bool = True) -> PagedPartial:
+                    interpret: bool = False) -> PagedPartial:
     """Paged flash-decode partials of q against this row's arena shard.
 
     q (B, Hq, L, hd) — L = 1 for decode, L = chunk for chunked prefill;

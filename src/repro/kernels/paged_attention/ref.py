@@ -15,7 +15,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 NEG_INF = -1e30
-UNOWNED_POS = jnp.int32(2 ** 30)     # past any q_pos: causally masked out
+UNOWNED_POS = 2 ** 30   # past any q_pos: causally masked out (a Python
+#                        int: importing this module touches no device)
 
 
 def gather_pages(kc, vc, table, *, stride, row, qrows):

@@ -88,7 +88,7 @@ def _state_passing(states, chunk_decay, init_state):
 def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
              Cm: jax.Array, init_state: Optional[jax.Array] = None, *,
              chunk: int = 128, backend: str = "jnp",
-             interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+             interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
     """Chunked SSD scan.  Returns (y (B,S,H,P), final_state (B,H,N,P))."""
     B, S, H, P = x.shape
     G, N = Bm.shape[2], Bm.shape[3]
@@ -99,20 +99,19 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
         init_state = jnp.zeros((B, H, N, P), jnp.float32)
 
     if backend == "pallas":
-        y_intra, states, cumexp = ssd_chunk_pallas(
+        y_h, states, cumexp = ssd_chunk_pallas(
             x, dt, A, Bm, Cm, chunk=L, interpret=interpret)
-        cr = Cm.reshape(B, nc, L, G, N)
-        states_in, final = _state_passing(states, cumexp[:, :, -1, :], init_state)
-        y = ssd_apply_pallas(y_intra, cr, cumexp,
-                             states_in.astype(jnp.float32), interpret=interpret)
-    else:
-        xr = x.reshape(B, nc, L, H, P)
-        dtr = dt.reshape(B, nc, L, H)
-        br = Bm.reshape(B, nc, L, G, N)
-        cr = Cm.reshape(B, nc, L, G, N)
-        y_intra, states, cumexp = _chunk_math_jnp(xr, dtr, A, br, cr)
-        states_in, final = _state_passing(states, cumexp[:, :, -1, :], init_state)
-        y = _apply_math_jnp(y_intra, cr, cumexp, states_in)
+        states_in, final = _state_passing(states, cumexp[:, :, :, 0, -1],
+                                          init_state)
+        return ssd_apply_pallas(y_h, Cm, cumexp, states_in,
+                                interpret=interpret), final
+    xr = x.reshape(B, nc, L, H, P)
+    dtr = dt.reshape(B, nc, L, H)
+    br = Bm.reshape(B, nc, L, G, N)
+    cr = Cm.reshape(B, nc, L, G, N)
+    y_intra, states, cumexp = _chunk_math_jnp(xr, dtr, A, br, cr)
+    states_in, final = _state_passing(states, cumexp[:, :, -1, :], init_state)
+    y = _apply_math_jnp(y_intra, cr, cumexp, states_in)
     return y.reshape(B, S, H, P), final
 
 

@@ -1,8 +1,12 @@
 """Serving launcher: batched greedy decoding with the SHMEM-grid server.
 
-Example (single fixed batch):
-  XLA_FLAGS=--xla_force_host_platform_device_count=16 \\
+Serves the architecture at its published widths over every device present
+(one chip: a 1x1 PE grid; four chips: 2x2; see ``repro.launch.mesh``).
+``--reduced`` serves the tiny smoke sibling instead, for CPU runs:
+
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --tokens 32
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=16 \\
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --reduced
 
 With ``--engine`` the same model is served through the continuous-batching
 engine (mixed-length workload, bucketed executables, paged-KV admission —
@@ -29,9 +33,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCH_NAMES, get_config
 from repro.configs.registry import reduced
-from repro.launch.mesh import make_smoke_mesh
+from repro.launch.mesh import device_mesh
 from repro.models import params as pm
-from repro.partition import DATA, MeshPlan, MODEL
+from repro.partition import DATA
+from repro.runtime.compile_cache import use_compile_cache
 from repro.serve.decode import cache_pspecs, cache_specs, make_decode_step
 
 
@@ -41,6 +46,10 @@ def main():
     ap.add_argument("--arch", "--config", dest="arch", required=True,
                     type=lambda s: s.replace("_", "-"),
                     choices=list(ARCH_NAMES))
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the tiny smoke sibling of --arch (fp32, "
+                         "d_model 64, vocab 256) instead of its published "
+                         "widths")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--tokens", type=int, default=32)
     ap.add_argument("--s-max", type=int, default=64)
@@ -97,23 +106,27 @@ def main():
                          "baseline)")
     args = ap.parse_args()
 
-    cfg = reduced(get_config(args.arch))
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = reduced(cfg)
     if cfg.enc_layers:
         raise SystemExit("whisper serving needs an encoder pass; see "
                          "tests/test_decode.py for the full harness")
-    mesh = make_smoke_mesh(data=1)
-    plan = MeshPlan((DATA, MODEL), (1, 16), 4, 4)
+    use_compile_cache()
 
     if args.engine or args.service or args.supervised:
         if args.mode != "gemv":
             print(f"note: --engine serves via the per-slot gemv decode "
                   f"layout; --mode {args.mode} ignored")
         if args.supervised:
-            return _main_supervised(cfg, plan, args)
+            # the worker owns the devices: this process never touches them
+            return _main_supervised(cfg, args)
+        mesh, plan = device_mesh()
         if args.service:
             return _main_service(cfg, mesh, plan, args)
         return _main_engine(cfg, mesh, plan, args)
 
+    mesh, plan = device_mesh()
     step, specs, pctx = make_decode_step(
         cfg, mesh, plan, batch=args.batch, s_max=args.s_max, mode=args.mode)
     params = pm.init_params(specs, seed=0)
@@ -142,8 +155,10 @@ def main():
         out_tokens.append(np.asarray(tok))
     dt = (time.time() - t0) / args.tokens
     seqs = np.stack(out_tokens, 1)
+    dev = jax.devices()[0]
     print(f"decoded {args.tokens} tokens x batch {args.batch} "
-          f"({dt*1e3:.1f} ms/token on host CPU)")
+          f"({dt*1e3:.1f} ms/token on {len(jax.devices())} x "
+          f"{dev.platform} {dev.device_kind}, compile included)")
     for b in range(min(args.batch, 2)):
         print(f"  seq[{b}]: {seqs[b][:16].tolist()} ...")
 
@@ -265,7 +280,7 @@ def _main_service(cfg, mesh, plan, args):
     print(json.dumps(snap, indent=2))
 
 
-def _main_supervised(cfg, plan, args):
+def _main_supervised(cfg, args):
     import asyncio
     import json
     import os
@@ -273,7 +288,7 @@ def _main_supervised(cfg, plan, args):
 
     from repro.serve.supervisor import (EngineSpec, ReplicaSupervisor,
                                         SupervisorConfig)
-    spec = EngineSpec(model_cfg=cfg, plan=plan,
+    spec = EngineSpec(model_cfg=cfg, plan=None,
                       engine_cfg=_engine_cfg(args), seed=0)
     prompts = _workload(cfg, args)
     sup_cfg = SupervisorConfig(

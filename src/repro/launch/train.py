@@ -1,10 +1,11 @@
 """Training launcher: --arch <id> end-to-end driver with fault tolerance.
 
-CPU-smoke by default (reduced config, 16 host devices); pass --full to use
-the full architecture config (requires the production mesh environment).
+Smoke run by default: the reduced config over every device present (see
+``repro.launch.mesh.device_mesh``); pass --full to use the full
+architecture config (requires the production mesh environment).
 
 Example:
-  XLA_FLAGS=--xla_force_host_platform_device_count=16 \\
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=16 \\
   PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b --steps 50
 """
 
@@ -21,11 +22,11 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import ARCH_NAMES, get_config
 from repro.configs.registry import reduced
 from repro.data.pipeline import DataConfig, make_batch
-from repro.launch.mesh import make_production_mesh, make_smoke_mesh, \
+from repro.launch.mesh import device_mesh, make_production_mesh, \
     production_plan
 from repro.models import params as pm
 from repro.optim.adamw import AdamWConfig, init_state
-from repro.partition import DATA, MeshPlan, MODEL
+from repro.partition import DATA
 from repro.runtime.fault_tolerance import FaultConfig, TrainController
 from repro.train.step import make_train_step
 
@@ -51,8 +52,7 @@ def main():
     cfg = get_config(args.arch)
     if not args.full:
         cfg = reduced(cfg)
-        mesh = make_smoke_mesh(data=1)
-        plan = MeshPlan((DATA, MODEL), (1, 16), 4, 4)
+        mesh, plan = device_mesh()
     else:
         mesh = make_production_mesh()
         plan = production_plan(mesh)

@@ -47,6 +47,8 @@ from repro.serve.service.service import (AdmissionRejected, ServiceError,
 from repro.serve.supervisor.spec import EngineSpec
 from repro.serve.supervisor.worker import WorkerConfig, worker_main
 
+_EXIT_TIMEOUT_S = 60.0              # SIGKILLed worker -> reaped
+
 
 @dataclasses.dataclass(frozen=True)
 class SupervisorConfig:
@@ -264,8 +266,6 @@ class ReplicaSupervisor:
 
     def _spawn(self) -> None:
         """Start one worker incarnation and wait for its engine build."""
-        os.environ.setdefault("XLA_FLAGS",
-                              "--xla_force_host_platform_device_count=32")
         cmd_r, cmd_w = self._ctx.Pipe(duplex=False)
         evt_r, evt_w = self._ctx.Pipe(duplex=False)
         wcfg = WorkerConfig(
@@ -305,9 +305,15 @@ class ReplicaSupervisor:
                     f"{self.config.ready_timeout_s}s")
 
     def _kill_worker(self) -> None:
+        """SIGKILL the worker and reap it: its devices are released only
+        when it has exited, and the next incarnation opens them."""
         if self._proc is not None and self._proc.is_alive():
             self._proc.kill()
-            self._proc.join(5.0)
+            self._proc.join(_EXIT_TIMEOUT_S)
+            if self._proc.is_alive():
+                raise ServiceError(
+                    f"replica worker did not exit within "
+                    f"{_EXIT_TIMEOUT_S}s of SIGKILL")
 
     def _close_worker(self) -> None:
         self._kill_worker()

@@ -79,17 +79,17 @@ def _leak_stats(engine, live) -> dict:
 
 def worker_main(spec, cmd, evt, wcfg: WorkerConfig) -> None:
     """Spawn entry: build the replica engine and drive it until told to
-    stop (or killed).  ``cmd``/``evt`` are the parent's pipe ends."""
-    # host device count must be pinned before the first jax import; the
-    # parent's environment normally carries this already — the setdefault
-    # only matters for a bare parent (e.g. a REPL without conftest)
-    os.environ.setdefault("XLA_FLAGS",
-                          "--xla_force_host_platform_device_count=32")
+    stop (or killed).  ``cmd``/``evt`` are the parent's pipe ends.  The
+    worker inherits the parent's environment (device flags included) and
+    keeps its compiled executables in the persistent compilation cache, so
+    a respawned incarnation reads them back instead of compiling again."""
     import numpy as np
 
+    from repro.runtime.compile_cache import use_compile_cache
     from repro.serve.engine.api import completion_of
     from repro.serve.resilience.checkpoint import thaw_request
 
+    use_compile_cache()
     engine = spec.build()
     inj = engine.engine_cfg.fault_injector
 

@@ -26,7 +26,8 @@ def test_cannon_mm(mkn, blocks, dtype):
     k1, k2 = jax.random.split(jax.random.PRNGKey(0))
     a = jax.random.normal(k1, (M, K), dtype)
     b = jax.random.normal(k2, (K, N), dtype)
-    out = blocked_matmul(a, b, block_m=bm, block_n=bn, block_k=bk)
+    out = blocked_matmul(a, b, block_m=bm, block_n=bn, block_k=bk,
+                         interpret=True)
     ref = matmul_ref(a, b)
     err = np.abs(out.astype(jnp.float32) - ref.astype(jnp.float32)).max()
     scale = max(1.0, float(np.abs(np.asarray(ref, np.float32)).max()))
@@ -50,7 +51,8 @@ def test_flash_attention(shape, dtype, causal):
     q = jax.random.normal(ks[0], (B, Hq, Sq, D), dtype)
     k = jax.random.normal(ks[1], (B, Hkv, Skv, D), dtype)
     v = jax.random.normal(ks[2], (B, Hkv, Skv, D), dtype)
-    out = flash_attention(q, k, v, causal=causal, q_offset=off)
+    out = flash_attention(q, k, v, causal=causal, q_offset=off,
+                          interpret=True)
     ref = attention_ref(q, k, v, causal=causal, q_offset=off)
     err = np.abs(out.astype(jnp.float32) - ref.astype(jnp.float32)).max()
     assert err < TOL[dtype], err
@@ -72,7 +74,8 @@ def test_ssd_scan(dims, backend):
     A = -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.3)
     Bm = jax.random.normal(ks[3], (B, S, G, N)) * 0.3
     Cm = jax.random.normal(ks[4], (B, S, G, N)) * 0.3
-    y, s = ssd_scan(x, dt, A, Bm, Cm, chunk=L, backend=backend)
+    y, s = ssd_scan(x, dt, A, Bm, Cm, chunk=L, backend=backend,
+                    interpret=backend == "pallas")
     y_ref, s_ref = ssd_ref(x, dt, A, Bm, Cm)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=1e-3, atol=1e-4)
