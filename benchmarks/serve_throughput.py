@@ -161,7 +161,6 @@ def run(report, steps=None, json_path="auto", config=None, timestamp=None,
         eng.queue.max_depth = 0
         for ev in eng.kernel_events().values():
             ev.launches = 0
-            ev.first_enqueue_t = ev.last_enqueue_t = ev.last_done_t = 0.0
 
         outs = generate(eng, prompts, sampling)
         assert all(len(c.tokens) == s.max_tokens
@@ -432,7 +431,6 @@ def run_prefix(report, json_path="auto", config=None, timestamp=None,
         eng.queue.max_depth = 0
         for ev in eng.kernel_events().values():
             ev.launches = 0
-            ev.first_enqueue_t = ev.last_enqueue_t = ev.last_done_t = 0.0
         outs = generate(eng, prompts, sampling)
         st = eng.stats
         results[label] = {
@@ -619,7 +617,6 @@ def run_speculation(report, json_path="auto", config=None, timestamp=None,
         eng.queue.max_depth = 0
         for ev in eng.kernel_events().values():
             ev.launches = 0
-            ev.first_enqueue_t = ev.last_enqueue_t = ev.last_done_t = 0.0
         outs = generate(eng, prompts, sampling)
         st = eng.stats
         dec = [c.decode_tok_s for c in outs if c.decode_tok_s is not None]

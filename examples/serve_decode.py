@@ -125,7 +125,7 @@ for c in outs[:3]:
 print(f"engine: {eng.stats.tokens_generated} tokens, "
       f"{eng.queue.n_executables} executables "
       f"(buckets {sorted(eng.kernel_events())}), "
-      f"{eng.throughput_tok_s():.1f} tok/s from KernelEvent stats")
+      f"{eng.throughput_tok_s():.1f} tok/s over the summed step time")
 print(f"engine: chunked prefill ingested "
       f"{eng.stats.prompt_tokens_ingested} prompt tokens in "
       f"{eng.stats.prefill_launches} launches "

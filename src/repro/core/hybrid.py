@@ -26,6 +26,7 @@ from functools import partial
 from typing import Any, Callable, Dict, Optional, Sequence
 
 import jax
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.shmem import ShmemGrid
@@ -33,12 +34,10 @@ from repro.core.shmem import ShmemGrid
 
 @dataclasses.dataclass
 class KernelEvent:
-    """Profiling record for one enqueued kernel (cl_event analogue).
-
-    Timestamps mirror OpenCL's CL_PROFILING_COMMAND_QUEUED/COMPLETE: the
-    queue stamps every enqueue with ``time.perf_counter()`` so host-side
-    throughput (tokens/sec in the serving engine) can be derived purely from
-    event records, without instrumenting the drive loop.
+    """Profiling record for one kernel (cl_event analogue): its lowering
+    cost stats, launch count and cumulative build time.  Host time around
+    launches is the drive loop's to measure (``EngineStats`` phase
+    counters), not the queue's.
     """
 
     name: str
@@ -47,15 +46,6 @@ class KernelEvent:
     collective_bytes: float = 0.0
     launches: int = 0
     build_time_s: float = 0.0
-    first_enqueue_t: float = 0.0    # perf_counter at first enqueue (0 = never)
-    last_enqueue_t: float = 0.0     # perf_counter at latest enqueue
-    last_done_t: float = 0.0        # perf_counter at the finish() that drained it
-
-    @property
-    def active_span_s(self) -> float:
-        """Wall-clock span this kernel was being launched over."""
-        end = self.last_done_t or self.last_enqueue_t
-        return max(0.0, end - self.first_enqueue_t) if self.first_enqueue_t else 0.0
 
 
 class HybridKernel:
@@ -76,10 +66,17 @@ class HybridKernel:
         self.donate = tuple(donate)
 
     def bind(self, mesh: Mesh) -> Callable:
+        """The jitted launch, named after the kernel so the compiled module
+        reads ``jit_<name>`` in a profiler trace."""
         body = partial(self.fn, self.grid)
         mapped = jax.shard_map(body, mesh=mesh, in_specs=self.in_specs,
                                out_specs=self.out_specs, check_vma=False)
-        return jax.jit(mapped, donate_argnums=self.donate)
+
+        def program(*args):
+            return mapped(*args)
+
+        program.__name__ = program.__qualname__ = self.name
+        return jax.jit(program, donate_argnums=self.donate)
 
 
 class CommandQueue:
@@ -111,9 +108,8 @@ class CommandQueue:
         record callers may already be aggregating against.
         """
         t0 = time.perf_counter()
-        fn = kernel.bind(self.mesh)
-        lowered = fn.lower(*example_args)
-        compiled = lowered.compile()
+        with TraceAnnotation("serve/build", name=kernel.name):
+            compiled = kernel.bind(self.mesh).lower(*example_args).compile()
         ev = self.events.setdefault(kernel.name, KernelEvent(kernel.name))
         ev.build_time_s += time.perf_counter() - t0
         if kernel.name not in self._compiled:
@@ -142,26 +138,15 @@ class CommandQueue:
         if kernel.name not in self._compiled:
             self.build(kernel, *args)
         out = self._compiled[kernel.name](*args)
-        ev = self.events[kernel.name]
-        ev.launches += 1
-        now = time.perf_counter()
-        if not ev.first_enqueue_t:
-            ev.first_enqueue_t = now
-        ev.last_enqueue_t = now
-        self._pending.append((kernel.name, out))
+        self.events[kernel.name].launches += 1
+        self._pending.append(out)
         self.max_depth = max(self.max_depth, len(self._pending))
         return out
 
     def finish(self):
         """clFinish: block until all enqueued work completes."""
-        drained = set()
-        for name, out in self._pending:
-            jax.block_until_ready(out)
-            drained.add(name)
+        jax.block_until_ready(self._pending)
         self._pending.clear()
-        now = time.perf_counter()
-        for name in drained:
-            self.events[name].last_done_t = now
 
 
 def _shape_bytes(shape_str: str) -> float:

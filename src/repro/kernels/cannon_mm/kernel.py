@@ -87,5 +87,5 @@ def matmul_pallas(
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret, name="cannon_mm",
     )(a, b)

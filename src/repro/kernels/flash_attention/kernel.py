@@ -113,5 +113,5 @@ def flash_attention_pallas(
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret, name="flash_attention",
     )(q, k, v)

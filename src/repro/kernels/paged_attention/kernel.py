@@ -154,5 +154,5 @@ def paged_attention_pallas(
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret, name="paged_attention",
     )(lidx, own, q, kc, vc, q_pos[:, None, :])

@@ -140,7 +140,7 @@ def ssd_chunk_pallas(x, dt, A, Bm, Cm, *, chunk: int, interpret: bool = False
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
-        interpret=interpret,
+        interpret=interpret, name="ssd_chunk",
     )(xh, dth, a3, _groups_major(Bm, nc, L), _groups_major(Cm, nc, L))
 
 
@@ -167,6 +167,6 @@ def ssd_apply_pallas(y_intra, Cm, cumexp, states_in, *, interpret: bool = False
         out_shape=jax.ShapeDtypeStruct((B, nc, H, L, P), y_intra.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
-        interpret=interpret,
+        interpret=interpret, name="ssd_apply",
     )(y_intra, _groups_major(Cm, nc, L), cumexp, states_in)
     return y.transpose(0, 1, 3, 2, 4).reshape(B, nc * L, H, P)

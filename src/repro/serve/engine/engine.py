@@ -11,8 +11,10 @@ by one position, and returns next-token logits.  ``dense``, ``moe``,
 operand list differs, and it differs by spec, not by string-matching
 mixers.  The host loop scatters request tokens in, gathers sampled tokens
 out, and drives the request state machine; ``queue.finish()`` after each
-launch is the paper's ``clFinish`` and stamps the ``KernelEvent``
-timestamps the throughput benchmark reads.
+launch is the paper's ``clFinish``.  Each step is a ``serve/step`` profiler
+span tiled by its phases (``serve/schedule``, ``serve/operands``,
+``serve/wait``, ``serve/fetch``, ``serve/publish``, ``serve/sample``),
+whose wall times also accumulate into ``EngineStats`` (``phases.py``).
 
 The arena is ONE device allocation shared by every bucket: it is donated
 through each enqueue — across *different* bucket executables, whose cache
@@ -56,6 +58,7 @@ from repro.serve.engine.block_cache import BlockPool, block_layout
 from repro.serve.engine.request import Request, RequestState, SamplingParams
 from repro.serve.engine.scheduler import (ScheduledStep, Scheduler,
                                           SchedulerConfig)
+from repro.serve.engine.phases import phase
 from repro.serve.engine.state_store import StateStore
 from repro.serve.state import layer_state_specs
 
@@ -146,6 +149,20 @@ class EngineStats:
     prefix_hits: int = 0                  # pages adopted at admission
     prefix_tokens_reused: int = 0         # prompt positions never prefilled
     prefix_evictions: int = 0             # cached pages recycled under pressure
+    # drive-loop wall time by phase, perf_counter_ns summed over steps
+    # (repro.serve.engine.phases): each is also a ``serve/<phase>`` span
+    step_ns: int = 0                      # the whole of step()
+    schedule_ns: int = 0                  # scheduler.schedule(), admission too
+    operand_ns: int = 0                   # operand arrays, device_put, enqueue
+    wait_ns: int = 0                      # blocking on the step's logits
+    fetch_ns: int = 0                     # logits copy to the host
+    sample_ns: int = 0                    # sampling + request advance
+    publish_ns: int = 0                   # prefix-cache page / dense publication
+    # work per launch
+    slots_launched: int = 0               # bucket width, summed over launches
+    slots_occupied: int = 0               # non-empty slots, summed over launches
+    chunk_positions: int = 0              # B x L, summed over chunk launches
+    chunk_positions_fed: int = 0          # positions fed by chunk launches
 
     @property
     def prefix_hit_rate(self) -> float:
@@ -251,6 +268,7 @@ class ServingEngine:
         self._bucket: Optional[int] = None
         self._rngs: Dict[str, np.random.Generator] = {}
         self.stats = EngineStats()
+        self._seq = 0                       # step() calls: serve/step's seq
         # pool prefix counters are monotone for the pool's lifetime; the
         # engine folds DELTAS into stats so `eng.stats = EngineStats()`
         # resets (benchmark warmup) stay correct
@@ -411,39 +429,48 @@ class ServingEngine:
         commit run under its retry/rollback/quarantine discipline; the
         unguarded path below is byte-identical to the pre-resilience
         engine."""
-        try:
-            if self.guard is not None:
-                self.guard.pre_schedule()
+        self._seq += 1
+        with phase(self.stats, "step", seq=self._seq) as span:
+            try:
+                return self._step(span)
+            finally:
+                # every prefix-cache mutation (admission adoption, eviction
+                # under allocation pressure, guard pool steals) happens
+                # inside a step — fold the pool's counter deltas on every
+                # exit path
+                self._fold_prefix_stats()
+
+    def _step(self, span: phase) -> bool:
+        if self.guard is not None:
+            self.guard.pre_schedule()
+        with phase(self.stats, "schedule"):
             sd = self.scheduler.schedule()
-            if sd is None:
-                if self.guard is not None:
-                    self.guard.release_stolen()  # idle: no pages held hostage
-                return False
-            self._note_migration(sd)
-            chunk = self._chunk_len(sd.max_remaining)
-            # speculative decoding replaces the pure-decode launch when any
-            # slot yields a usable draft; with no drafts this round the
-            # plain serve_step launch below runs unchanged.  With a guard
-            # armed the verify launch runs under the same retry/rollback/
-            # quarantine discipline as every other step.
-            if chunk is None and self.spec is not None:
-                if self.guard is not None:
-                    handled = self.guard.spec_step(sd)
-                    if handled is not None:
-                        return True
-                elif self.spec.step(sd):
-                    return True
+        if sd is None:
             if self.guard is not None:
-                return self.guard.step(sd, chunk)
-            rows, fed = self._launch(sd, chunk)
-            self._commit(sd, rows, fed)
-            self.queue.finish()     # clFinish: stamps KernelEvent.last_done_t
-            return True
-        finally:
-            # every prefix-cache mutation (admission adoption, eviction
-            # under allocation pressure, guard pool steals) happens inside
-            # a step — fold the pool's counter deltas on every exit path
-            self._fold_prefix_stats()
+                self.guard.release_stolen()  # idle: no pages held hostage
+            return False
+        self._note_migration(sd)
+        chunk = self._chunk_len(sd.max_remaining)
+        span.annotate(bucket=sd.bucket, chunk=chunk or 0,
+                      occupied=sum(r is not None for r in sd.slots))
+        # speculative decoding replaces the pure-decode launch when any
+        # slot yields a usable draft; with no drafts this round the plain
+        # serve_step launch below runs unchanged.  With a guard armed the
+        # verify launch runs under the same retry/rollback/quarantine
+        # discipline as every other step.
+        if chunk is None and self.spec is not None:
+            if self.guard is not None:
+                handled = self.guard.spec_step(sd)
+                if handled is not None:
+                    return True
+            elif self.spec.step(sd):
+                return True
+        if self.guard is not None:
+            return self.guard.step(sd, chunk)
+        rows, fed = self._launch(sd, chunk)
+        self._commit(sd, rows, fed)
+        self.queue.finish()         # clFinish
+        return True
 
     def _launch(self, sd: ScheduledStep, chunk: Optional[int]):
         """Build operands and enqueue ONE step kernel for ``sd``; returns
@@ -453,113 +480,143 @@ class ServingEngine:
         site fires before the enqueue, ``device`` after)."""
         B = sd.bucket
         inj = self.engine_cfg.fault_injector
-        pos = np.zeros((B,), np.int32)
-        has_pages = self.store.needs_pages
-        has_dense = self.store.has_dense
-        table = np.full((B, self._table_width), -1, np.int32)
-        slots = np.full((B,), -1, np.int32)
-        fed = [0] * B
-        dev = lambda a: jax.device_put(jnp.asarray(a), self._vec_sharding)
-        dev2 = lambda a: jax.device_put(jnp.asarray(a), self._table_sharding)
-        if chunk is None:
-            tokens = np.zeros((B,), np.int32)
-            for s, r in enumerate(sd.slots):
-                if r is not None:
-                    tokens[s] = r.next_token
+        with phase(self.stats, "operands"):
+            pos = np.zeros((B,), np.int32)
+            has_pages = self.store.needs_pages
+            has_dense = self.store.has_dense
+            table = np.full((B, self._table_width), -1, np.int32)
+            slots = np.full((B,), -1, np.int32)
+            fed = [0] * B
+            dev = lambda a: jax.device_put(jnp.asarray(a), self._vec_sharding)
+            dev2 = lambda a: jax.device_put(jnp.asarray(a),
+                                            self._table_sharding)
+            if chunk is None:
+                tokens = np.zeros((B,), np.int32)
+                for s, r in enumerate(sd.slots):
+                    if r is not None:
+                        tokens[s] = r.next_token
+                        pos[s] = r.num_cached
+                        if has_pages:
+                            table[s, :len(r.blocks.ids)] = r.blocks.ids
+                        if has_dense:
+                            slots[s] = r.dense_slot
+                        fed[s] = 1
+                ops = ([dev2(table)] if has_pages else []) \
+                    + ([dev(slots)] if has_dense else [])
+                if inj is not None:
+                    inj.fire("launch")
+                logits, self.store.arena = self.queue.enqueue(
+                    self._kernel(B), self.params, self.store.arena,
+                    dev(tokens), dev(pos), *ops)
+            else:
+                tokens = np.zeros((B, chunk), np.int32)
+                n_valid = np.zeros((B,), np.int32)
+                for s, r in enumerate(sd.slots):
+                    if r is None:
+                        continue
+                    n = self._fed_count(r, chunk)
+                    seq = r.seq_tokens
+                    tokens[s, :n] = seq[r.num_cached:r.num_cached + n]
                     pos[s] = r.num_cached
+                    n_valid[s] = n
                     if has_pages:
                         table[s, :len(r.blocks.ids)] = r.blocks.ids
                     if has_dense:
                         slots[s] = r.dense_slot
-                    fed[s] = 1
-            ops = ([dev2(table)] if has_pages else []) \
-                + ([dev(slots)] if has_dense else [])
-            if inj is not None:
-                inj.fire("launch")
-            logits, self.store.arena = self.queue.enqueue(
-                self._kernel(B), self.params, self.store.arena,
-                dev(tokens), dev(pos), *ops)
-        else:
-            tokens = np.zeros((B, chunk), np.int32)
-            n_valid = np.zeros((B,), np.int32)
-            for s, r in enumerate(sd.slots):
-                if r is None:
-                    continue
-                n = self._fed_count(r, chunk)
-                seq = r.seq_tokens
-                tokens[s, :n] = seq[r.num_cached:r.num_cached + n]
-                pos[s] = r.num_cached
-                n_valid[s] = n
-                if has_pages:
-                    table[s, :len(r.blocks.ids)] = r.blocks.ids
-                if has_dense:
-                    slots[s] = r.dense_slot
-                fed[s] = n
-            ops = ([dev2(table)] if has_pages else []) \
-                + ([dev(slots)] if has_dense else [])
-            if inj is not None:
-                inj.fire("launch")
-            logits, self.store.arena = self.queue.enqueue(
-                self._chunk_kernel(B, chunk), self.params, self.store.arena,
-                dev2(tokens), dev(pos), dev(n_valid), *ops)
+                    fed[s] = n
+                ops = ([dev2(table)] if has_pages else []) \
+                    + ([dev(slots)] if has_dense else [])
+                if inj is not None:
+                    inj.fire("launch")
+                logits, self.store.arena = self.queue.enqueue(
+                    self._chunk_kernel(B, chunk), self.params,
+                    self.store.arena, dev2(tokens), dev(pos), dev(n_valid),
+                    *ops)
         if inj is not None:
             inj.fire("device")      # the enqueue "happened"; stats below
             #                         only count steps that got this far
+        st = self.stats
         if chunk is not None:
-            self.stats.prefill_chunk_launches += 1
-        self.stats.steps += 1
-        self.stats.peak_blocks_used = max(self.stats.peak_blocks_used,
-                                          self.pool.n_used)
-        if self.store.slot_pool is not None:
-            self.stats.peak_dense_slots_used = max(
-                self.stats.peak_dense_slots_used, self.store.slot_pool.n_used)
+            st.prefill_chunk_launches += 1
+            st.chunk_positions += B * chunk
+            st.chunk_positions_fed += sum(fed)
+        st.steps += 1
+        self._count_slots(sd)
         if sd.is_prefill:
-            self.stats.prefill_launches += 1
+            st.prefill_launches += 1
         else:
-            self.stats.decode_launches += 1
-        rows = np.asarray(logits[:, 0, :self.cfg.vocab_size])
-        return rows, fed
+            st.decode_launches += 1
+        return self._to_host(logits), fed
+
+    def _count_slots(self, sd: ScheduledStep) -> None:
+        """Per-launch occupancy counters, shared with the verify launch."""
+        st = self.stats
+        st.slots_launched += sd.bucket
+        st.slots_occupied += sum(r is not None for r in sd.slots)
+        st.peak_blocks_used = max(st.peak_blocks_used, self.pool.n_used)
+        if self.store.slot_pool is not None:
+            st.peak_dense_slots_used = max(st.peak_dense_slots_used,
+                                           self.store.slot_pool.n_used)
+
+    def _to_host(self, logits, pos=0) -> np.ndarray:
+        """The step's logits at ``pos`` (an index or slice over positions),
+        cut to the vocabulary, on the host: blocking on the sliced array
+        is the ``serve/wait`` phase, the copy ``serve/fetch``."""
+        with phase(self.stats, "wait"):
+            sl = logits[:, pos, :self.cfg.vocab_size]
+            sl.block_until_ready()
+        with phase(self.stats, "fetch"):
+            return np.asarray(sl)
 
     def _commit(self, sd: ScheduledStep, rows: np.ndarray, fed,
                 skip=frozenset()) -> None:
         """Advance the request state machine with a successful launch's
         results.  Slots in ``skip`` (guard-poisoned rows) advance NOTHING —
         their pre-step snapshot was restored, so next step re-feeds the
-        same positions."""
-        for s, r in enumerate(sd.slots):
-            if r is None or s in skip:
-                continue
-            n = fed[s]
-            # the launch fed seq_tokens[num_cached : num_cached + n]; its
-            # logits extend the sequence iff that range ends at the last
-            # known token (the per-token samples_this_step rule, chunked)
-            will_sample = r.num_cached + n == len(r.seq_tokens)
-            # count only the fed positions inside the prompt: replayed
-            # OUTPUT tokens (recompute preemption) are not prompt ingestion,
-            # while re-fed prompt positions are — the kernel really re-ran
-            prev_cached = r.num_cached
-            self.stats.prompt_tokens_ingested += max(
-                0, min(prev_cached + n, len(r.prompt)) - prev_cached)
-            r.num_cached += n
-            r.fault_failures = 0    # a committed step clears the quarantine
-            #                         count — "repeatedly" means consecutively
-            self._publish_filled_pages(r, prev_cached, r.num_cached)
-            self._maybe_publish_dense(r)
-            if not will_sample:
-                continue
-            tok = self._sample(r, rows[s])
-            r.output_tokens.append(tok)
-            if len(r.output_tokens) == 1:
-                r.first_token_t = time.perf_counter()
-            self.stats.tokens_generated += 1
-            if r.state == RequestState.PREFILL:
-                r.transition(RequestState.DECODE)
-            reason = r.finish_reason_for(tok, self.engine_cfg.s_max)
-            if reason is not None:
-                self.scheduler.complete(r, reason)
-                self._rngs.pop(r.request_id, None)
-                if self.spec is not None:
-                    self.spec.release(r.request_id)
+        same positions.  Every slot's cache advance and prefix publication
+        (``serve/publish``) comes before any sampling (``serve/sample``),
+        so each phase is one span per step."""
+        sampled = []
+        with phase(self.stats, "publish"):
+            for s, r in enumerate(sd.slots):
+                if r is None or s in skip:
+                    continue
+                n = fed[s]
+                # the launch fed seq_tokens[num_cached : num_cached + n];
+                # its logits extend the sequence iff that range ends at the
+                # last known token (the per-token samples_this_step rule,
+                # chunked)
+                if r.num_cached + n == len(r.seq_tokens):
+                    sampled.append(s)
+                # count only the fed positions inside the prompt: replayed
+                # OUTPUT tokens (recompute preemption) are not prompt
+                # ingestion, while re-fed prompt positions are — the kernel
+                # really re-ran
+                prev_cached = r.num_cached
+                self.stats.prompt_tokens_ingested += max(
+                    0, min(prev_cached + n, len(r.prompt)) - prev_cached)
+                r.num_cached += n
+                r.fault_failures = 0    # a committed step clears the
+                #                         quarantine count — "repeatedly"
+                #                         means consecutively
+                self._publish_filled_pages(r, prev_cached, r.num_cached)
+                self._maybe_publish_dense(r)
+        with phase(self.stats, "sample"):
+            for s in sampled:
+                r = sd.slots[s]
+                tok = self._sample(r, rows[s])
+                r.output_tokens.append(tok)
+                if len(r.output_tokens) == 1:
+                    r.first_token_t = time.perf_counter()
+                self.stats.tokens_generated += 1
+                if r.state == RequestState.PREFILL:
+                    r.transition(RequestState.DECODE)
+                reason = r.finish_reason_for(tok, self.engine_cfg.s_max)
+                if reason is not None:
+                    self.scheduler.complete(r, reason)
+                    self._rngs.pop(r.request_id, None)
+                    if self.spec is not None:
+                        self.spec.release(r.request_id)
 
     def _fold_prefix_stats(self) -> None:
         """Fold the pool's monotone prefix counters into ``stats`` as
@@ -733,14 +790,11 @@ class ServingEngine:
                                     "verify_bs"))}
 
     def throughput_tok_s(self) -> float:
-        """Generated tokens / wall-span of step-kernel activity, derived
-        purely from CommandQueue KernelEvent timestamps."""
-        evs = [e for e in self.kernel_events().values() if e.first_enqueue_t]
-        if not evs or not self.stats.tokens_generated:
+        """Generated tokens over the summed wall time of ``step()``
+        (``EngineStats.step_ns``); 0.0 before any step."""
+        if not self.stats.step_ns:
             return 0.0
-        t0 = min(e.first_enqueue_t for e in evs)
-        t1 = max(e.last_done_t or e.last_enqueue_t for e in evs)
-        return self.stats.tokens_generated / max(t1 - t0, 1e-9)
+        return self.stats.tokens_generated * 1e9 / self.stats.step_ns
 
     def peak_kv_bytes(self) -> int:
         """Peak resident state bytes: pool occupancy x per-page footprint

@@ -44,6 +44,7 @@ import numpy as np
 from repro.core.hybrid import HybridKernel
 from repro.serve.decode import make_prefill_chunk_body
 from repro.serve.engine.block_cache import PoolExhausted
+from repro.serve.engine.phases import phase
 from repro.serve.engine.request import RequestState
 from repro.serve.spec.accept import accept_draft
 from repro.serve.spec.config import SpeculationConfig
@@ -229,29 +230,25 @@ class SpecDecoder:
         ``device`` after — the same contract as ``ServingEngine._launch``).
         """
         eng = self.eng
-        has_pages = eng.store.needs_pages
-        has_dense = eng.store.has_dense
-        dev = lambda a: jax.device_put(jnp.asarray(a), eng._vec_sharding)
-        dev2 = lambda a: jax.device_put(jnp.asarray(a), eng._table_sharding)
-        ops = ([dev2(rnd.table)] if has_pages else []) \
-            + ([dev(rnd.slots)] if has_dense else [])
         inj = eng.engine_cfg.fault_injector
-        if inj is not None:
-            inj.fire("launch")
-        logits, eng.store.arena = eng.queue.enqueue(
-            self._kernel(rnd.sd.bucket), eng.params, eng.store.arena,
-            dev2(rnd.tokens), dev(rnd.pos), dev(rnd.n_valid), *ops)
+        with phase(eng.stats, "operands"):
+            dev = lambda a: jax.device_put(jnp.asarray(a), eng._vec_sharding)
+            dev2 = lambda a: jax.device_put(jnp.asarray(a),
+                                            eng._table_sharding)
+            ops = ([dev2(rnd.table)] if eng.store.needs_pages else []) \
+                + ([dev(rnd.slots)] if eng.store.has_dense else [])
+            if inj is not None:
+                inj.fire("launch")
+            logits, eng.store.arena = eng.queue.enqueue(
+                self._kernel(rnd.sd.bucket), eng.params, eng.store.arena,
+                dev2(rnd.tokens), dev(rnd.pos), dev(rnd.n_valid), *ops)
         if inj is not None:
             inj.fire("device")      # the enqueue "happened"; stats below
             #                         only count rounds that got this far
-        st = eng.stats
-        st.steps += 1
-        st.spec_launches += 1
-        st.peak_blocks_used = max(st.peak_blocks_used, eng.pool.n_used)
-        if eng.store.slot_pool is not None:
-            st.peak_dense_slots_used = max(st.peak_dense_slots_used,
-                                           eng.store.slot_pool.n_used)
-        return np.asarray(logits[:, :, :eng.cfg.vocab_size])
+        eng.stats.steps += 1
+        eng.stats.spec_launches += 1
+        eng._count_slots(rnd.sd)
+        return eng._to_host(logits, slice(None))
 
     def rollback_in_flight(self) -> int:
         """Rewind the uncommitted draft tail of the in-flight round (if

@@ -110,7 +110,10 @@ def test_mixed_length_workload_one_executable_per_bucket(mesh16, plan16):
     assert eng.stats.prefill_launches < eng.stats.prompt_tokens_ingested
     assert eng.stats.prompt_tokens_ingested == sum(len(p) for p in prompts)
     assert eng.stats.tokens_generated == sum(len(c.tokens) for c in outs)
-    assert eng.throughput_tok_s() > 0.0
+    # throughput reads the step counter: tokens over summed step() time
+    assert eng.stats.step_ns > 0
+    assert eng.throughput_tok_s() == pytest.approx(
+        eng.stats.tokens_generated * 1e9 / eng.stats.step_ns)
     assert eng.stats.prefill_launches > 0 and eng.stats.decode_launches > 0
     # the paged arena is ONE bucket-invariant allocation: every leaf keeps
     # the (G, n_pes, n_blocks_local, stride, kvh, hd) shape across the whole

@@ -1,7 +1,10 @@
 """CommandQueue / KernelEvent unit tests (cl_command_queue analogue)."""
 
+import dataclasses
+
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from jax.sharding import PartitionSpec as P
 
@@ -51,8 +54,11 @@ def test_enqueue_finish_event_lifecycle(mesh16):
     np.testing.assert_allclose(np.asarray(out), 3.0)
     ev = queue.events["addk"]
     assert ev.launches == 1
-    assert 0.0 < ev.first_enqueue_t <= ev.last_enqueue_t <= ev.last_done_t
-    assert ev.active_span_s >= 0.0
+    # the event is a cost-and-count record: host time around launches is
+    # the drive loop's phase counters, so the event carries no timestamps
+    assert {f.name for f in dataclasses.fields(ev)} == {
+        "name", "flops", "bytes_accessed", "collective_bytes", "launches",
+        "build_time_s"}
 
 
 def test_max_depth_tracks_inflight_high_water(mesh16):
@@ -76,8 +82,8 @@ def test_max_depth_tracks_inflight_high_water(mesh16):
 def test_event_accounting_under_mixed_prefill_decode_traffic(mesh16, plan16):
     """KernelEvent invariants under real mixed engine traffic: staggered
     submits force prefill chunk launches to interleave with decode-phase
-    slots, and every event record must stay consistent —
-    ``active_span_s`` spans first-enqueue..last-done, launches partition
+    slots, and every event record must stay consistent — the step counter
+    holds the wall time the launches were driven over, launches partition
     across executables, ``n_executables`` matches the distinct kernels
     actually used, and the engine's finish()-per-step discipline keeps the
     queue's high-water depth at exactly 1."""
@@ -112,10 +118,14 @@ def test_event_accounting_under_mixed_prefill_decode_traffic(mesh16, plan16):
     assert sum(ev.launches for ev in events.values()) == eng.stats.steps
     for name, ev in events.items():
         assert ev.launches > 0, name
-        assert 0.0 < ev.first_enqueue_t <= ev.last_enqueue_t, name
-        # the engine finishes every step: each event was drained
-        assert ev.last_done_t >= ev.last_enqueue_t, name
-        assert ev.active_span_s == ev.last_done_t - ev.first_enqueue_t > 0.0
+    # the wall time the launches were driven over is the step counter,
+    # tiled by the phases inside it; throughput reads it
+    st = eng.stats
+    phases = (st.schedule_ns + st.operand_ns + st.wait_ns + st.fetch_ns
+              + st.sample_ns + st.publish_ns)
+    assert 0 < phases <= st.step_ns
+    assert eng.throughput_tok_s() == pytest.approx(
+        st.tokens_generated * 1e9 / st.step_ns)
     # finish()-per-step discipline: never more than one in-flight enqueue
     assert eng.queue.max_depth == 1
     assert eng.queue.depth == 0
