@@ -18,6 +18,7 @@ import numpy as np
 
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.paged_attention import paged_attention
+from repro.kernels.paged_attention.kernel import walk_shape
 from repro.kernels.ssd_scan import ssd_ref, ssd_scan
 from repro.models.attention import chunked_attention
 
@@ -76,18 +77,20 @@ def run(report):
     np.random.default_rng(0).shuffle(table)
     table = jnp.asarray(table.reshape(B, T))
     q_pos = jnp.full((B, 1), T * stride - 1, jnp.int32)
+    kv_len = q_pos[:, 0] + 1
     f_gather = jax.jit(lambda *a: paged_attention(
         *a, stride=stride, row=0, qrows=1, backend="jnp"))
     report("paged_decode_gather_us",
-           round(_bench(f_gather, q, kc, vc, table, q_pos), 1),
+           round(_bench(f_gather, q, kc, vc, table, q_pos, kv_len), 1),
            f"jnp: materializes (B,{T * stride},{kvh},{hd}) K/V per call")
     copy_bytes = 2 * B * T * stride * kvh * hd * 4      # K and V, fp32
     report("paged_decode_gather_copy_KB", round(copy_bytes / 1024, 1),
            "gathered-copy traffic the fused kernel eliminates per launch")
-    page_kb = 2 * stride * kvh * hd * 4 / 1024
+    _, pages = walk_shape(1, stride, T)
+    page_kb = 2 * 2 * pages * stride * kvh * hd * 4 / 1024
     report("paged_fused_vmem_page_KB", round(page_kb, 1),
-           f"fused kernel VMEM working set: ONE (stride={stride}) page pair "
-           "+ running (m,l,acc)")
+           f"fused kernel VMEM working set: two blocks of {pages} (stride="
+           f"{stride}) page pairs + running (m,l,acc)")
 
     # Pallas cannon_mm structural numbers (transfer to TPU directly)
     bm = bn = bk = 256

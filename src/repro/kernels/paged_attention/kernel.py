@@ -7,30 +7,48 @@ and per launch, a gathered ``(B, T * stride, kvh, hd)`` K/V copy
 (``jnp.take`` over the page arena) before attending; this kernel instead
 takes the **arena shard and the block table directly** and performs the
 page gather inside the kernel: the K/V BlockSpec index maps read the
-scalar-prefetched local page index, so each grid step DMAs exactly one
-physical page HBM->VMEM and streams it through the running flash-decode
-statistics.  No gathered intermediate ever exists.
+scalar-prefetched local page index of the step's table entry, so each
+grid step DMAs exactly the physical page it needs HBM->VMEM (the pipeline
+double-buffers it behind the step before).  No gathered intermediate ever
+exists.
 
-Grid: ``(B, T)`` — slot-major, table entries innermost ("arbitrary": the
-running (m, l, acc) scratch carries across t).  Per (b, t) the kernel
+The walk.  Tile ``(b, qi)`` — ``tq`` chunk columns of slot b — reads
+positions below its bound
 
-  * skips the *compute* for pages this grid row does not own
-    (``own[b, t] == 0``: entry is unallocated, or the physical id routes
-    to another row) via ``pl.when`` — no MXU/VPU work and no accumulator
-    update; the block pipeline still prefetches the (clipped) page 0 pair
-    for those steps, a known cost of the dense ``(B, T)`` grid;
-  * masks positions causally in *global* coordinates: page t covers
-    positions ``[t * stride, (t+1) * stride)`` regardless of which
-    physical page backs it (tables may be scrambled arbitrarily);
-  * accumulates streaming-softmax partials, flushed as ``(m, l, acc)``
-    **LSE partial outputs** — NOT normalized attention — so the SHMEM
-    row-merge (``repro.models.attention.combine_partials``) composes
-    unchanged across the grid rows that shard the physical page space.
+    bound = min(kv_len[b], q_start[b] + (qi + 1) * tq)
+
+(one past the tile's last causal position, cut at one past the slot's last
+valid query position).  The grid is one dimension whose length is set on
+the device (:func:`_work_list`): one step per table entry each tile reads,
+tiles in order, entries 0 .. ``ceil(bound / stride) - 1``.  So
+
+  * a tile at or past the slot's valid columns (``qi * tq >= kv_len -
+    q_start``: chunk padding, or an idle slot with ``kv_len = 0``) takes no
+    step at all, and no entry at or past a tile's bound is fetched: the
+    work follows each slot's real length, not ``s_max / stride``;
+  * each step copies its page into a block of ``P`` pages held in VMEM,
+    and the block is scored once, at its last page: one ``(kvh, tq *
+    group, P * stride)`` product per block (query heads grouped by kv
+    head, no ``repeat`` of K/V), and the running ``(m, l, acc)`` rescaled
+    once per block, not once per page;
+  * positions mask causally in *global* coordinates: entry t covers
+    positions ``[t * stride, (t+1) * stride)`` regardless of which physical
+    page backs it (tables may be scrambled arbitrarily); keys at or past
+    the bound, or on entries this grid row does not own (``own[b, t] ==
+    0``: unallocated, or routed to another row), are zeroed and masked, so
+    whatever a page holds there is never read into a result;
+  * ``(m, l, acc)`` flush as **LSE partial outputs** — NOT normalized
+    attention — so the SHMEM row-merge
+    (``repro.models.attention.combine_partials``) composes unchanged across
+    the grid rows that shard the physical page space.
 
 The same kernel serves one-position decode (L = 1) and chunked prefill
-(L = chunk): chunk columns past a slot's ``n_valid`` produce garbage
-partials that the caller never reads (the prefill body extracts the last
-valid position only), exactly like the jnp path.
+(L = chunk); the tile sizes follow the call's static shapes
+(:func:`walk_shape`).  Chunk columns past a slot's ``n_valid`` are padding
+the caller never reads (the prefill body extracts the last valid position
+only): they attend to the slot's valid keys where their tile is walked,
+and read ``(m, l, acc) = (-1e30, 0, 0)`` where it is not — finite either
+way.
 """
 
 from __future__ import annotations
@@ -39,55 +57,134 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+KEYS_PER_BLOCK = 256    # positions of one scored block (P = this / stride)
+QUERY_TILE = 64         # chunk columns per query tile, where L divides
 
 
-def _paged_flash_kernel(lidx_ref, own_ref, q_ref, k_ref, v_ref, qpos_ref,
-                        m_ref, l_ref, acc_ref, m_s, l_s, acc_s, *,
-                        n_entries: int, stride: int, group: int,
+def walk_shape(L: int, stride: int, T: int):
+    """``(tq, P)``: query columns per tile and table entries per key block,
+    from the call's static shapes.  ``tq`` divides ``L`` and ``P``
+    divides ``T``, so no tile or block runs off its array; ``P <= 32``, so
+    a block's ownership fits one int32 of bits."""
+    tq = QUERY_TILE if L > QUERY_TILE and L % QUERY_TILE == 0 else L
+    P = max(1, min(T, KEYS_PER_BLOCK // stride, 32))
+    while T % P:
+        P -= 1
+    return tq, P
+
+
+def _work_list(own, q_start, kv_len, *, tq, stride, pages, nQ):
+    """The walk as a list of grid steps, one table entry each, built on the
+    device.
+
+    Tile ``(b, qi)`` reads positions below ``bound = min(kv_len[b],
+    q_start[b] + (qi + 1) * tq)``: it steps through entries 0 ..
+    ``ceil(bound / stride) - 1`` of slot b's table, and a tile at or past
+    the slot's valid columns (chunk padding, an idle slot) takes no step.
+    Returns ``(n_steps, steps, walked, owned)``: the step count (at least
+    1); ``steps`` (B * nQ * T,) int32, ``tile * T + entry`` per step with
+    ``tile = b * nQ + qi`` (the entries past the step count are never
+    read); ``walked`` (B, nQ), the tiles that step; and ``owned`` (B,
+    T / pages), bit i set where entry ``block * pages + i`` is this
+    row's."""
+    B, T = own.shape
+    nK = T // pages
+    i32 = jnp.int32
+    qi = lax.broadcasted_iota(i32, (B, nQ), 1)
+    q0 = lax.broadcast_in_dim(q_start, (B, nQ), (0,))
+    kl = lax.broadcast_in_dim(kv_len, (B, nQ), (0,))
+    bound = lax.min(lax.min(kl, q0 + (qi + 1) * tq),
+                    lax.full_like(kl, T * stride))
+    walked = qi * tq < kl - q0
+    n_ent = lax.select(walked, lax.div(bound + stride - 1, stride),
+                       lax.full_like(kl, 0)).reshape(B * nQ)
+    end = lax.cumsum(n_ent)
+    # step s belongs to the first tile whose entries end past it (the one
+    # step of a launch in which no tile walks names the last tile, which
+    # it only initializes)
+    s = lax.broadcasted_iota(i32, (B * nQ * T, B * nQ), 0)
+    ends = lax.broadcast_in_dim(end, s.shape, (1,))
+    before = ends <= s
+    tile = lax.min(jnp.sum(before, axis=1, dtype=i32),
+                   lax.full((B * nQ * T,), B * nQ - 1, i32))
+    start = jnp.max(lax.select(before, ends, lax.full_like(ends, 0)), axis=1)
+    steps = tile * T + s[:, 0] - start
+    bits = lax.shift_left(lax.full((B, nK, pages), 1, i32),
+                          lax.broadcasted_iota(i32, (B, nK, pages), 2))
+    owned = jnp.sum(lax.select(own.reshape(B, nK, pages) > 0, bits,
+                               lax.full_like(bits, 0)), axis=-1, dtype=i32)
+    return lax.max(end[-1], 1), steps, walked, owned
+
+
+def _paged_flash_kernel(steps_ref, lidx_ref, owned_ref, q0_ref, kv_len_ref,
+                        q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
+                        kblk, vblk, *, stride: int, pages: int,
+                        n_entries: int, nQ: int, tq: int, group: int,
                         scale: float):
-    b = pl.program_id(0)
-    t = pl.program_id(1)
+    T, S = n_entries, pages * stride
+    step = steps_ref[pl.program_id(0)]
+    # (lax.div / lax.rem: every operand is >= 0, and they lower to one op)
+    tile, e = lax.div(step, T), lax.rem(step, T)
+    b, qi = lax.div(tile, nQ), lax.rem(tile, nQ)
+    j, i = lax.div(e, pages), lax.rem(e, pages)
+    walked = qi * tq < kv_len_ref[b] - q0_ref[b]
+    bound = jnp.minimum(jnp.minimum(kv_len_ref[b], q0_ref[b] + (qi + 1) * tq),
+                        T * stride)
+    last = (i == pages - 1) | ((e + 1) * stride >= bound)
 
-    @pl.when(t == 0)
+    @pl.when(e == 0)
     def _init():
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    @pl.when(own_ref[b, t] > 0)
-    def _step():
-        q = q_ref[0].astype(jnp.float32) * scale      # (Hq, L, hd)
-        k = k_ref[0].astype(jnp.float32)              # (stride, kvh, hd)
-        v = v_ref[0].astype(jnp.float32)
-        # GQA: q head h attends stored kv head h // group
-        kr = jnp.repeat(k.transpose(1, 0, 2), group, axis=0)  # (Hq, stride, hd)
-        vr = jnp.repeat(v.transpose(1, 0, 2), group, axis=0)
-        s = jnp.einsum("hld,hsd->hls", q, kr)         # (Hq, L, stride)
-        L = q.shape[1]
-        # table entry t labels positions [t*stride, (t+1)*stride) no matter
-        # which physical page backs it — the causal mask runs on the LABELS
-        kv_pos = t * stride + jax.lax.broadcasted_iota(
-            jnp.int32, (L, stride), 1)
-        mask = qpos_ref[0, 0][:, None] >= kv_pos
-        s = jnp.where(mask[None], s, NEG_INF)
-        m_prev = m_s[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[..., None])
+    # gather the block's pages; it is scored once, at its last page
+    kblk[i] = k_ref[0]
+    vblk[i] = v_ref[0]
+
+    # (the one step of a launch in which no tile walks computes nothing)
+    @pl.when(walked & last)
+    def _block():
+        owned = owned_ref[b, j]
+
+        def key_ok(shape, axis):
+            """Keys of block j this row owns, inside the tile's bound."""
+            k = lax.broadcasted_iota(jnp.int32, shape, axis)
+            bits = lax.shift_right_logical(lax.full(shape, owned, jnp.int32),
+                                           lax.div(k, stride))
+            return ((bits & 1) > 0) & (k < bound - j * S)
+
+        rows = q_ref.shape[3]
+        f32 = jnp.float32
+        q = q_ref[0, 0].astype(f32) * scale                # (kvh, rows, hd)
+        # whatever an unread key holds (a NaN past the bound, a page left
+        # from another block) stays out
+        full = (S,) + kblk.shape[2:]
+        ok_rows = jnp.broadcast_to(key_ok((S, 1, 1), 0), full)
+        k, v = (lax.select(ok_rows, blk[...].reshape(full).astype(f32),
+                           lax.full(full, 0.0, f32)).transpose(1, 0, 2)
+                for blk in (kblk, vblk))                   # (kvh, S, hd)
+        s = jnp.einsum("hrd,hsd->hrs", q, k, preferred_element_type=f32)
+        q_pos = q0_ref[b] + qi * tq + lax.div(lax.broadcasted_iota(
+            jnp.int32, (1, rows, S), 1), group)            # row r: column r // group
+        kv_pos = j * S + lax.broadcasted_iota(jnp.int32, (1, rows, S), 2)
+        mask = jnp.broadcast_to(key_ok((1, rows, S), 2) & (kv_pos <= q_pos),
+                                s.shape)
+        s = lax.select(mask, s, lax.full(s.shape, NEG_INF, f32))
+        m_prev = m_ref[0, 0]
+        m_new = lax.max(m_prev, jnp.max(s, axis=-1))
+        p = lax.select(mask, jnp.exp(s - m_new[..., None]),
+                       lax.full(s.shape, 0.0, f32))
         alpha = jnp.exp(m_prev - m_new)
-        l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=-1)
-        acc_s[...] = acc_s[...] * alpha[..., None] + jnp.einsum(
-            "hls,hsd->hld", p, vr)
-        m_s[...] = m_new
-
-    @pl.when(t == n_entries - 1)
-    def _flush():
-        m_ref[0] = m_s[...]
-        l_ref[0] = l_s[...]
-        acc_ref[0] = acc_s[...]
+        l_ref[0, 0] = l_ref[0, 0] * alpha + jnp.sum(p, axis=-1)
+        acc_ref[0, 0] = acc_ref[0, 0] * alpha[..., None] + jnp.einsum(
+            "hrs,hsd->hrd", p, v, preferred_element_type=f32)
+        m_ref[0, 0] = m_new
 
 
 def paged_attention_pallas(
@@ -96,63 +193,90 @@ def paged_attention_pallas(
     vc: jax.Array,           # (n_blocks_local, stride, kvh, hd)
     lidx: jax.Array,         # (B, T) int32 local page index (clipped; see ops)
     own: jax.Array,          # (B, T) int32 1 = this row owns the entry
-    q_pos: jax.Array,        # (B, L) int32 global query positions
+    q_start: jax.Array,      # (B,) int32 global position of query column 0
+    kv_len: jax.Array,       # (B,) int32 one past the last valid query
     *,
     stride: int,
     scale=None,
     interpret: bool = False,
 ):
-    """Fused paged flash-decode partials: ``(m, l, acc)`` fp32.
+    """Fused paged flash-decode partials: ``(m, l, acc)`` fp32, shaped
+    ``(B, Hq, L)``, ``(B, Hq, L)``, ``(B, Hq, L, hd)``.
 
-    ``lidx``/``own`` are the scalar-prefetch form of the block table (one
-    integer pair per table entry, computed by :func:`ops.table_routing`);
-    the K/V index maps read ``lidx`` so the page gather happens in the DMA
-    engine, never as a materialized copy.
+    Query column j of slot b sits at global position ``q_start[b] + j``;
+    columns at or past ``kv_len[b] - q_start[b]`` are padding.
+    ``lidx``/``own`` are the block table resolved against this grid row
+    (one integer pair per table entry, computed by
+    :func:`ops.table_routing`); ``lidx`` rides in SMEM with the step list,
+    each block's ownership bits, ``q_start`` and ``kv_len``, and the K/V
+    index maps read it, so the page gather happens in the DMA engine,
+    never as a materialized copy.
     """
     B, Hq, L, hd = q.shape
     _, _, kvh, _ = kc.shape
     T = lidx.shape[1]
     assert Hq % kvh == 0, (Hq, kvh)
+    group = Hq // kvh
     scale = scale if scale is not None else hd ** -0.5
-    kernel = functools.partial(
-        _paged_flash_kernel, n_entries=T, stride=stride, group=Hq // kvh,
-        scale=scale)
+    tq, P = walk_shape(L, stride, T)
+    nQ, rows = L // tq, tq * group
+    # (B, Hq, L, hd) -> (B, nQ, kvh, tq * group, hd): a tile's query heads
+    # grouped under their kv head, row = column * group + g
+    qt = q.reshape(B, kvh, group, nQ, tq, hd).transpose(0, 3, 1, 4, 2, 5
+                                                        ).reshape(
+        B, nQ, kvh, rows, hd)
+    n_steps, steps, walked, owned = _work_list(
+        own, q_start.astype(jnp.int32), kv_len.astype(jnp.int32), tq=tq,
+        stride=stride, pages=P, nQ=nQ)
+    kernel = functools.partial(_paged_flash_kernel, stride=stride, pages=P,
+                               n_entries=T, nQ=nQ, tq=tq, group=group,
+                               scale=scale)
+
+    def page_index(s, steps_ref, lidx_ref, *_):
+        st = steps_ref[s]
+        return lidx_ref[lax.div(st, T * nQ), lax.rem(st, T)], 0, 0, 0
+
+    def tile_index(s, steps_ref, *_):
+        tile = lax.div(steps_ref[s], T)
+        return lax.div(tile, nQ), lax.rem(tile, nQ), 0, 0
+
+    page = pl.BlockSpec((1, stride, kvh, hd), page_index)
+    stats = pl.BlockSpec((1, 1, kvh, rows), tile_index)
+    tile = pl.BlockSpec((1, 1, kvh, rows, hd),
+                        lambda *a: tile_index(*a) + (0,))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, T),
-        in_specs=[
-            pl.BlockSpec((1, Hq, L, hd), lambda b, t, lidx, own: (b, 0, 0, 0)),
-            # the in-kernel gather: entry (b, t)'s page is DMA'd straight
-            # from the arena at the scalar-prefetched local index
-            pl.BlockSpec((1, stride, kvh, hd),
-                         lambda b, t, lidx, own: (lidx[b, t], 0, 0, 0)),
-            pl.BlockSpec((1, stride, kvh, hd),
-                         lambda b, t, lidx, own: (lidx[b, t], 0, 0, 0)),
-            # (B, 1, L): a (1, L) block of a 2-D (B, L) array breaks the
-            # TPU rule that a block's last two dims tile by (8, 128) or
-            # span the array
-            pl.BlockSpec((1, 1, L), lambda b, t, lidx, own: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, Hq, L), lambda b, t, lidx, own: (b, 0, 0)),
-            pl.BlockSpec((1, Hq, L), lambda b, t, lidx, own: (b, 0, 0)),
-            pl.BlockSpec((1, Hq, L, hd), lambda b, t, lidx, own: (b, 0, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((Hq, L), jnp.float32),
-            pltpu.VMEM((Hq, L), jnp.float32),
-            pltpu.VMEM((Hq, L, hd), jnp.float32),
-        ],
+        num_scalar_prefetch=5,
+        grid=(n_steps,),
+        in_specs=[tile, page, page],
+        out_specs=[stats, stats, tile],
+        scratch_shapes=[pltpu.VMEM((P, stride, kvh, hd), kc.dtype),
+                        pltpu.VMEM((P, stride, kvh, hd), vc.dtype)],
     )
-    return pl.pallas_call(
+    m, l, acc = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hq, L), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hq, L), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hq, L, hd), jnp.float32),
+            jax.ShapeDtypeStruct((B, nQ, kvh, rows), jnp.float32),
+            jax.ShapeDtypeStruct((B, nQ, kvh, rows), jnp.float32),
+            jax.ShapeDtypeStruct((B, nQ, kvh, rows, hd), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret, name="paged_attention",
-    )(lidx, own, q, kc, vc, q_pos[:, None, :])
+    )(steps, lidx, owned, q_start.astype(jnp.int32),
+      kv_len.astype(jnp.int32), qt, kc, vc)
+
+    # a tile that took no step was never written: it reads as a row that
+    # owns no key
+    walked = walked[:, :, None, None]
+    m = jnp.where(walked, m, NEG_INF)
+    l = jnp.where(walked, l, 0.0)
+    acc = jnp.where(walked[..., None], acc, 0.0)
+
+    def untile(x):           # (B, nQ, kvh, rows, ...) -> (B, Hq, L, ...)
+        tail = x.shape[4:]
+        x = x.reshape(B, nQ, kvh, tq, group, *tail)
+        x = jnp.moveaxis(x, (1, 3), (3, 4))              # (B,kvh,g,nQ,tq,..)
+        return x.reshape(B, Hq, L, *tail)
+
+    return untile(m), untile(l), untile(acc)

@@ -4,9 +4,10 @@
     identical math to the serving engine's historical paged path; keeps a
     gathered ``(B, T * stride, kvh, hd)`` K/V copy alive per call.
   * ``backend="pallas"``  — the fused kernel (kernel.py): the block table
-    rides as a scalar-prefetch operand and every page is DMA'd from the
-    arena exactly once, in place; compiled for the TPU, or run through the
-    Pallas interpreter with ``interpret=True`` (CPU CI).
+    and each slot's ``kv_len`` ride as scalar-prefetch operands and every
+    page a query tile needs is DMA'd from the arena once, in place;
+    compiled for the TPU, or run through the Pallas interpreter with
+    ``interpret=True`` (CPU CI).
 
 Both return **LSE partials** ``(m, l, acc)`` over this grid row's pages, so
 the SHMEM row-merge (``combine_partials``) downstream is backend-blind.
@@ -52,7 +53,8 @@ def table_routing(table: jax.Array, row, qrows: int
 @functools.partial(jax.jit, static_argnames=("stride", "qrows", "scale",
                                              "backend", "interpret"))
 def paged_attention(q: jax.Array, kc: jax.Array, vc: jax.Array,
-                    table: jax.Array, q_pos: jax.Array, *, stride: int,
+                    table: jax.Array, q_pos: jax.Array, kv_len: jax.Array,
+                    *, stride: int,
                     row, qrows: int, scale: Optional[float] = None,
                     backend: str = "jnp",
                     interpret: bool = False) -> PagedPartial:
@@ -61,9 +63,12 @@ def paged_attention(q: jax.Array, kc: jax.Array, vc: jax.Array,
     q (B, Hq, L, hd) — L = 1 for decode, L = chunk for chunked prefill;
     kc/vc (n_blocks_local, stride, kvh, hd) local page arena;
     table (B, T) physical page ids (-1 = unallocated);
-    q_pos (B, L) global query positions (padding columns simply produce
-    partials the caller never reads); ``row`` may be traced (the grid row
-    index under shard_map).
+    q_pos (B, L) global query positions, ``q_pos[b, j] = q_pos[b, 0] + j``;
+    kv_len (B,) one past each slot's last valid query position (0 for an
+    idle slot): the fused kernel walks no table entry at or past it, and
+    columns at or past it are padding whose partials the caller never
+    reads (the jnp reference ignores it); ``row`` may be traced (the grid
+    row index under shard_map).
     """
     if backend not in ("jnp", "pallas"):
         raise ValueError(f"unknown paged_attention backend {backend!r}: "
@@ -74,7 +79,7 @@ def paged_attention(q: jax.Array, kc: jax.Array, vc: jax.Array,
                                         scale=scale)
         return PagedPartial(m, l, acc)
     lidx, own = table_routing(table, row, qrows)
-    m, l, acc = paged_attention_pallas(q, kc, vc, lidx, own, q_pos,
-                                       stride=stride, scale=scale,
+    m, l, acc = paged_attention_pallas(q, kc, vc, lidx, own, q_pos[:, 0],
+                                       kv_len, stride=stride, scale=scale,
                                        interpret=interpret)
     return PagedPartial(m, l, acc)

@@ -314,7 +314,8 @@ def _rows_pmax(grid):
     return lambda t: lax.pmax(t, grid.axis, axis_index_groups=groups)
 
 
-def _paged_partial(q, kc, vc, table, q_pos, stride, row, qrows, backend):
+def _paged_partial(q, kc, vc, table, q_pos, kv_len, stride, row, qrows,
+                   backend):
     """Per-row paged-attention partials, backend-dispatched.
 
     ``backend="jnp"`` materializes the gathered per-slot K/V runs
@@ -323,8 +324,9 @@ def _paged_partial(q, kc, vc, table, q_pos, stride, row, qrows, backend):
     the table straight to the fused kernel
     (:mod:`repro.kernels.paged_attention`): the page gather happens inside
     the kernel's DMA index maps, so no ``(B, T * stride, ...)`` gathered
-    copy ever exists.  Both return LSE partials, so the SHMEM row-merge
-    downstream (``combine_partials``) is backend-blind."""
+    copy ever exists, and each slot's walk stops at ``kv_len`` (one past
+    its last valid query position).  Both return LSE partials, so the
+    SHMEM row-merge downstream (``combine_partials``) is backend-blind."""
     if backend == "jnp":
         kg, vg, kv_pos = gather_pages(kc, vc, table, stride=stride, row=row,
                                       qrows=qrows)
@@ -335,8 +337,8 @@ def _paged_partial(q, kc, vc, table, q_pos, stride, row, qrows, backend):
     if q_pos.ndim == 1:      # scalar-pos decode: shared across the batch
         q_pos = jnp.broadcast_to(q_pos[None, :],
                                  (q.shape[0], q_pos.shape[0]))
-    return paged_attention(q, kc, vc, table, q_pos, stride=stride, row=row,
-                           qrows=qrows, backend="pallas",
+    return paged_attention(q, kc, vc, table, q_pos, kv_len, stride=stride,
+                           row=row, qrows=qrows, backend="pallas",
                            interpret=interpret)
 
 
@@ -384,8 +386,10 @@ def _attn_decode_paged(pctx, p, x, cfg, kc, vc, pos, table, stride,
     vc = vc.at[li_w, off_w].set(v[:, 0].astype(vc.dtype), mode="drop")
 
     q_pos = jnp.reshape(pos, (1,)) if jnp.ndim(pos) == 0 else pos[:, None]
+    # an idle slot (table all -1) walks nothing
+    kv_len = jnp.where(jnp.any(table >= 0, axis=1), posv + 1, 0)
     part = _paged_partial(q.transpose(0, 2, 1, 3), kc, vc, table, q_pos,
-                          stride, i, qrows, backend)
+                          kv_len, stride, i, qrows, backend)
     out = combine_partials(part, _rows_pmax(grid), grid.psum_rows)
     out = out.transpose(0, 2, 1, 3).reshape(B, 1, hq_loc * hd)
     y = dense(pctx, out.astype(x.dtype), p["wo"])
@@ -441,7 +445,7 @@ def _attn_prefill_chunk_paged(pctx, p, x, cfg, kc, vc, pos, n_valid, table,
     vc = vc.at[li_w, off_w].set(v.astype(vc.dtype), mode="drop")
 
     part = _paged_partial(q.transpose(0, 2, 1, 3), kc, vc, table, pos2,
-                          stride, i, qrows, backend)
+                          pos + n_valid, stride, i, qrows, backend)
     out = combine_partials(part, _rows_pmax(grid), grid.psum_rows)
     out = out.transpose(0, 2, 1, 3).reshape(B, L, hq_loc * hd)
     y = dense(pctx, out.astype(x.dtype), p["wo"])

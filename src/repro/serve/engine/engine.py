@@ -163,6 +163,9 @@ class EngineStats:
     slots_occupied: int = 0               # non-empty slots, summed over launches
     chunk_positions: int = 0              # B x L, summed over chunk launches
     chunk_positions_fed: int = 0          # positions fed by chunk launches
+    # paged-attention walk per launch of a paged model (0 without pages)
+    kv_pages_walked: int = 0              # ceil(kv_len / stride), occupied slots
+    kv_pages_listed: int = 0              # bucket x table width
 
     @property
     def prefix_hit_rate(self) -> float:
@@ -542,6 +545,7 @@ class ServingEngine:
             st.chunk_positions_fed += sum(fed)
         st.steps += 1
         self._count_slots(sd)
+        self._count_pages(pos + np.asarray(fed, np.int32))
         if sd.is_prefill:
             st.prefill_launches += 1
         else:
@@ -557,6 +561,16 @@ class ServingEngine:
         if self.store.slot_pool is not None:
             st.peak_dense_slots_used = max(st.peak_dense_slots_used,
                                            self.store.slot_pool.n_used)
+
+    def _count_pages(self, kv_len: np.ndarray) -> None:
+        """The paged-attention walk of one launch, shared with the verify
+        launch: ``kv_len`` (B,) is one past each slot's last fed position
+        (0 for an empty slot), the bound the kernel walks each slot's
+        table to."""
+        if self.store.needs_pages:
+            stride = self.engine_cfg.block_pos_stride
+            self.stats.kv_pages_walked += int(np.sum(-(-kv_len // stride)))
+            self.stats.kv_pages_listed += len(kv_len) * self._table_width
 
     def _to_host(self, logits, pos=0) -> np.ndarray:
         """The step's logits at ``pos`` (an index or slice over positions),

@@ -248,6 +248,7 @@ class SpecDecoder:
         eng.stats.steps += 1
         eng.stats.spec_launches += 1
         eng._count_slots(rnd.sd)
+        eng._count_pages(rnd.pos + rnd.n_valid)
         return eng._to_host(logits, slice(None))
 
     def rollback_in_flight(self) -> int:

@@ -167,3 +167,38 @@ def test_named_kernel_lowers_under_its_name(mesh16, name):
                      in_specs=(P(MODEL),), out_specs=P(MODEL), name=name)
     text = k.bind(mesh16).lower(jnp.ones((16, 4))).as_text()
     assert f"module @jit_{name} " in text
+
+
+def test_paged_walk_counters_match_a_hand_count(mesh16, plan16):
+    """Two slots of different lengths, greedy, stride 4 (table width 8):
+    each launch walks ceil(kv_len / 4) pages per occupied slot and lists
+    bucket x 8 entries.  By hand, per launch (bucket; kv_len per slot):
+
+      1. chunk of 4, bucket 2: a feeds 0-3 (kv 4); b feeds its whole
+         prompt 0-2 (kv 3) and samples -> 1 + 1 pages
+      2. chunk of 4 (the ladder's smallest covers a's 2 left), bucket 2:
+         a feeds 4-5 (kv 6) and samples; b feeds 3 (kv 4) and finishes
+         -> 2 + 1
+      3. decode, bucket 1: a feeds 6 (kv 7) and finishes -> 2
+    """
+    eng = _engine(mesh16, plan16)
+    eng.submit(list(range(1, 7)), SamplingParams(max_tokens=2))
+    eng.submit([7, 8, 9], SamplingParams(max_tokens=2))
+    eng.drain()
+    st = eng.stats
+    assert (st.prefill_chunk_launches, st.launches) == (2, 3)
+    assert st.kv_pages_walked == 2 + 3 + 2
+    assert st.kv_pages_listed == (2 + 2 + 1) * 8
+
+
+def test_paged_walk_counters_stay_zero_without_pages(mesh16, plan16):
+    from repro.configs import get_config
+    from repro.configs.registry import reduced
+    ec = EngineConfig(s_max=32, buckets=(1, 2), block_pos_stride=4,
+                      prefill_chunks=(4,))
+    eng = build_engine(reduced(get_config("mamba2-780m")), mesh16, plan16,
+                       engine_cfg=ec, seed=0)
+    eng.submit([1, 2, 3, 4, 5], SamplingParams(max_tokens=3))
+    eng.drain()
+    assert eng.stats.launches > 0
+    assert eng.stats.kv_pages_walked == eng.stats.kv_pages_listed == 0

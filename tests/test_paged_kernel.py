@@ -65,28 +65,131 @@ def _rand_case(rng, *, B, T, stride, kvh, hd, Hq, qrows, L, holes=True):
     return table, arenas, q, q_pos
 
 
-def _merged(backend, table, arenas, q, q_pos, stride, qrows):
+def _merged(backend, table, arenas, q, q_pos, stride, qrows, kv_len=None):
+    if kv_len is None:
+        kv_len = q_pos[:, -1] + 1
     parts = []
     for row, (kc, vc) in enumerate(arenas):
         parts.append(paged_attention(
             jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
-            jnp.asarray(table), jnp.asarray(q_pos), stride=stride,
-            row=row, qrows=qrows, backend=backend, interpret=True))
+            jnp.asarray(table), jnp.asarray(q_pos), jnp.asarray(kv_len),
+            stride=stride, row=row, qrows=qrows, backend=backend,
+            interpret=True))
     return np.asarray(merge_rows(parts))
 
 
-@pytest.mark.parametrize("L", [1, 8], ids=["decode", "chunk"])
-def test_fused_kernel_matches_gather_ref_scrambled(L):
+# The walk's geometry at stride 32: 8 table entries (256 positions) per key
+# block, 16 entries per slot, so a slot spans two blocks; chunks of 128
+# columns run as two query tiles of 64.
+WALK = dict(T=16, stride=32, kvh=2, hd=16, Hq=4)
+
+
+def _walk_case(rng, *, pos, n_valid, L, qrows, holes=False, idle=()):
+    """Explicit slot positions, valid columns and idle slots on the walk
+    geometry; returns the case plus each slot's ``kv_len``."""
+    B = len(pos)
+    table, arenas, q, _ = _rand_case(rng, B=B, L=L, qrows=qrows, holes=holes,
+                                     **WALK)
+    pos = np.asarray(pos, np.int32)
+    kv_len = pos + np.asarray(n_valid, np.int32)
+    for b in idle:
+        table[b] = -1
+        kv_len[b] = 0
+    q_pos = pos[:, None] + np.arange(L, dtype=np.int32)[None]
+    return table, arenas, q, q_pos, kv_len
+
+
+def _poison_unread(table, arenas, kv_len, stride, qrows):
+    """NaN into every arena position the walk must never read: positions
+    at or past each slot's ``kv_len``, and pages no table lists."""
+    arenas = [(kc.copy(), vc.copy()) for kc, vc in arenas]
+    live = np.zeros((len(arenas), arenas[0][0].shape[0], stride), bool)
+    for b, row in enumerate(table):
+        for t, pid in enumerate(row):
+            if pid >= 0:
+                n = int(np.clip(kv_len[b] - t * stride, 0, stride))
+                live[pid % qrows, pid // qrows, :n] = True
+    for r, (kc, vc) in enumerate(arenas):
+        kc[~live[r]] = np.nan
+        vc[~live[r]] = np.nan
+    return arenas
+
+
+SCRAMBLED = {
+    # the original cases: stride 8, four entries (one block), qrows 2
+    "decode": dict(L=1),
+    "chunk": dict(L=8),
+    # kv_len 0 (idle slot, table all -1), 1, exactly a block boundary, one
+    # past it, and T * stride
+    "kv-len-edges": dict(pos=[0, 0, 255, 256, 511], n_valid=[1] * 5, L=1,
+                         qrows=2, idle=(0,)),
+    "holes-qrows1": dict(pos=[3, 300, 0], n_valid=[1] * 3, L=1, qrows=1,
+                         holes=True),
+    "holes-qrows2": dict(pos=[200, 31, 262], n_valid=[1] * 3, L=1, qrows=2,
+                         holes=True),
+    # chunk columns valid: 1 (a decode rider), a partial second tile, all
+    "chunk-n-valid": dict(pos=[300, 17, 0], n_valid=[1, 70, 128], L=128,
+                          qrows=2),
+    "nan-past-kv-len": dict(pos=[300, 17, 0], n_valid=[1, 70, 128], L=128,
+                            qrows=2, poison=True),
+}
+
+
+@pytest.mark.parametrize("case", list(SCRAMBLED), ids=list(SCRAMBLED))
+def test_fused_kernel_matches_gather_ref_scrambled(case):
     """The load-bearing claim: in-place page reads == materialized gather,
-    after the LSE row merge, for scrambled tables + holes + GQA."""
+    after the LSE row merge, for scrambled tables + holes + GQA, over each
+    slot's valid columns, wherever its walk stops.  Padding columns stay
+    finite, and a NaN in any position the walk must not read never reaches
+    a result."""
     rng = np.random.default_rng(0)
-    stride, qrows = 8, 2
-    table, arenas, q, q_pos = _rand_case(
-        rng, B=3, T=4, stride=stride, kvh=2, hd=16, Hq=4, qrows=qrows, L=L)
-    o_ref = _merged("jnp", table, arenas, q, q_pos, stride, qrows)
-    o_pal = _merged("pallas", table, arenas, q, q_pos, stride, qrows)
-    rel = np.abs(o_ref - o_pal).max() / (np.abs(o_ref).max() + 1e-9)
+    spec = dict(SCRAMBLED[case])
+    if "pos" not in spec:
+        stride, qrows = 8, 2
+        table, arenas, q, q_pos = _rand_case(
+            rng, B=3, T=4, stride=stride, kvh=2, hd=16, Hq=4, qrows=qrows,
+            L=spec["L"])
+        kv_len = q_pos[:, -1] + 1
+    else:
+        poison = spec.pop("poison", False)
+        stride, qrows = WALK["stride"], spec["qrows"]
+        table, arenas, q, q_pos, kv_len = _walk_case(rng, **spec)
+    o_ref = _merged("jnp", table, arenas, q, q_pos, stride, qrows, kv_len)
+    if "pos" in spec and poison:
+        arenas = _poison_unread(table, arenas, kv_len, stride, qrows)
+    o_pal = _merged("pallas", table, arenas, q, q_pos, stride, qrows, kv_len)
+    assert np.isfinite(o_pal).all()
+    valid = q_pos < kv_len[:, None]                  # (B, L) read columns
+    a, b = (np.moveaxis(o, 2, 1)[valid] for o in (o_ref, o_pal))
+    rel = np.abs(a - b).max() / (np.abs(a).max() + 1e-9)
     assert rel < 1e-5, rel
+
+
+@pytest.mark.parametrize("kv_len", [[0, 0, 0], [0, 37, 1]],
+                         ids=["all-idle", "mixed"])
+def test_work_list_steps_name_entries_inside_the_table(kv_len):
+    """The device-built step list of an 8-column chunk in tiles of 4: each
+    step names a tile and a table entry inside the kernel's arrays, and
+    the steps visit exactly the entries below each tile's bound.  The
+    all-idle launch (the engine's warm-up) still takes one step, on the
+    last tile: the interpreter clamps an index past an array, the chip
+    halts on it."""
+    from repro.kernels.paged_attention.kernel import _work_list
+    B, T, stride, pages, tq, nQ = 3, 16, 4, 4, 4, 2
+    kv_len = np.asarray(kv_len, np.int32)
+    q_start = np.asarray([0, 30, 0], np.int32)
+    n, steps, walked, _ = _work_list(
+        jnp.ones((B, T), jnp.int32), jnp.asarray(q_start),
+        jnp.asarray(kv_len), tq=tq, stride=stride, pages=pages, nQ=nQ)
+    got = [divmod(int(x), T) for x in np.asarray(steps)[:int(n)]]
+    want = []
+    for b in range(B):
+        for qi in range(nQ):
+            if qi * tq < kv_len[b] - q_start[b]:
+                bound = min(kv_len[b], q_start[b] + (qi + 1) * tq)
+                want += [(b * nQ + qi, e) for e in range(-(-bound // stride))]
+    assert got == (want or [(B * nQ - 1, 0)])
+    assert np.asarray(walked).sum() == len({t for t, _ in want})
 
 
 def test_fused_kernel_single_row_identity_table():
@@ -109,8 +212,9 @@ def test_paged_attention_rejects_unknown_backend():
         rng, B=1, T=2, stride=4, kvh=2, hd=8, Hq=2, qrows=1, L=1)
     with pytest.raises(ValueError, match="backend"):
         paged_attention(jnp.asarray(q), *map(jnp.asarray, arenas[0]),
-                        jnp.asarray(table), jnp.asarray(q_pos), stride=4,
-                        row=0, qrows=1, backend="cuda")
+                        jnp.asarray(table), jnp.asarray(q_pos),
+                        jnp.asarray(q_pos[:, -1] + 1), stride=4, row=0,
+                        qrows=1, backend="cuda")
 
 
 # ---------------------------------------------------------------------------

@@ -53,22 +53,24 @@ def _compile(fn, *shapes, sharding, kernel):
     return text
 
 
-# qwen2-0.5b on one chip: 14 query heads over 2 kv heads of width 64, pages
-# of 16 positions, s_max 2048 -> 128 table entries.
-HQ, KVH, HD, STRIDE, T, N_PAGES = 14, 2, 64, 16, 128, 1024
+# qwen2-0.5b on one chip, as the chat cell serves it: 14 query heads over 2
+# kv heads of width 64, pages of 16 positions, s_max 4096 -> 256 table
+# entries over an 8192-page arena; decode, and each prefill chunk length.
+HQ, KVH, HD, STRIDE, T, N_PAGES = 14, 2, 64, 16, 256, 8192
 
 
-@pytest.mark.parametrize("L", [1, 256], ids=["decode", "prefill"])
+@pytest.mark.parametrize("L", [1, 16, 64, 256],
+                         ids=["decode", "prefill16", "prefill64", "prefill"])
 def test_paged_attention_compiles_for_v5e(one_chip, L):
-    B = 8
+    B = 16
     _compile(
-        lambda q, kc, vc, lidx, own, qp: paged_attention_pallas(
-            q, kc, vc, lidx, own, qp, stride=STRIDE),
+        lambda q, kc, vc, lidx, own, q0, kv_len: paged_attention_pallas(
+            q, kc, vc, lidx, own, q0, kv_len, stride=STRIDE),
         ((B, HQ, L, HD), jnp.bfloat16),
         ((N_PAGES, STRIDE, KVH, HD), jnp.bfloat16),
         ((N_PAGES, STRIDE, KVH, HD), jnp.bfloat16),
-        ((B, T), jnp.int32), ((B, T), jnp.int32), ((B, L), jnp.int32),
-        sharding=one_chip, kernel="paged_attention")
+        ((B, T), jnp.int32), ((B, T), jnp.int32), ((B,), jnp.int32),
+        ((B,), jnp.int32), sharding=one_chip, kernel="paged_attention")
 
 
 # mamba2-780m on one chip: 48 heads of width 64, state 128, one group; the
