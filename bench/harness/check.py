@@ -70,6 +70,14 @@ def _logits_fn(mode: str):
     return jax.jit(lambda h, e: logits_rows(h, e, mode))
 
 
+def output_head(ref_name: str, s: dict, w):
+    """The ``(V, D)`` matrix the logits are read from: the reference's
+    ``head(s, w)`` where it defines one (an untied head), else the
+    embedding."""
+    ref = files.module("reference", ref_name)
+    return ref.head(s, w) if hasattr(ref, "head") else w["embed"]
+
+
 def reference_logits(ref_name, s, w, prompt, tokens, mode="f32"):
     """Logits ``(len(tokens), V)`` that predict each served token."""
     seq = list(prompt) + list(tokens[:-1])
@@ -80,8 +88,8 @@ def reference_logits(ref_name, s, w, prompt, tokens, mode="f32"):
     nb = -(-n // ROWS) * ROWS
     rows = jax.lax.dynamic_slice_in_dim(
         jnp.pad(h, ((0, nb), (0, 0))), start, nb, axis=0)
-    fn = _logits_fn(mode)
-    out = [np.asarray(fn(rows[i:i + ROWS], w["embed"]))
+    fn, head = _logits_fn(mode), output_head(ref_name, s, w)
+    out = [np.asarray(fn(rows[i:i + ROWS], head))
            for i in range(0, nb, ROWS)]
     return np.concatenate(out)[:n]
 

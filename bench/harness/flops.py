@@ -4,7 +4,7 @@ needs, computed from shapes and real lengths.
 Nothing here reads the program's own cost analysis: a kernel that does
 work nobody needs (padding, pages past a slot's length) does not raise its
 count, so removing that work reads as a gain.  ``s`` is a configuration's
-``model`` block (``bench/configs/<name>.json``).
+sizes as served (``program.sizes``).
 """
 
 from __future__ import annotations
@@ -27,30 +27,16 @@ def peak(device_kind: str) -> dict:
 
 
 # -- whole model -------------------------------------------------------------
+#
+# What one position costs through the layers belongs to the architecture:
+# each reference (``bench/reference/<name>.py``) gives ``position_flops(s)``.
+# What depends on the context and the sampling is counted here, from
+# ``s["layer_pattern"]``.
 
-def dense_layer_params(s) -> int:
-    D, F, hd = s["d_model"], s["d_ff"], s["head_dim"]
-    nq, nk = s["n_heads"] * hd, s["n_kv_heads"] * hd
-    return D * nq + 2 * D * nk + nq * D + 3 * D * F
-
-
-def ssm_layer_params(s) -> int:
-    D, di, H = s["d_model"], s["d_inner"], s["ssm_heads"]
-    GN = s["ssm_groups"] * s["ssm_state"]
-    return D * (2 * di + 2 * GN + H) + di * D
-
-
-def body_flops_per_position(s) -> float:
-    """Matmul FLOPs of one position through every layer (2 per weight),
-    plus the SSM recurrence (state update and read-out) where there is
-    one; attention over the context is counted apart."""
-    L = s["n_layers"]
-    if s["family"] == "ssm":
-        H, P, N = s["ssm_heads"], s["ssm_headdim"], s["ssm_state"]
-        conv = 2 * s["conv_kernel"] * (s["d_inner"]
-                                       + 2 * s["ssm_groups"] * N)
-        return L * (2 * ssm_layer_params(s) + conv + 4 * H * N * P)
-    return L * 2 * dense_layer_params(s)
+def layers(s, mixer: str) -> int:
+    """How many of the model's layers have the mixer ``mixer``."""
+    pat = s["layer_pattern"]
+    return s["n_layers"] // len(pat) * sum(1 for m, _ in pat if m == mixer)
 
 
 def head_flops(s) -> float:
@@ -58,20 +44,23 @@ def head_flops(s) -> float:
 
 
 def attention_flops(s, pos: int, n: int) -> float:
-    """QK and PV FLOPs, all layers, of ``n`` positions fed from ``pos``:
-    position ``pos + j`` attends to ``pos + j + 1`` keys."""
-    if s["family"] == "ssm":
+    """QK and PV FLOPs, all attention layers, of ``n`` positions fed from
+    ``pos``: position ``pos + j`` attends to ``pos + j + 1`` keys."""
+    L = layers(s, "attn")
+    if not L:
         return 0.0
     keys = n * pos + n * (n + 1) // 2
-    return s["n_layers"] * 4.0 * s["n_heads"] * s["head_dim"] * keys
+    return L * 4.0 * s["n_heads"] * s["head_dim"] * keys
 
 
-def launch_model_flops(s, slots) -> float:
+def launch_model_flops(s, slots, ref) -> float:
     """Model FLOPs that one launch needs: ``slots`` is a list of
-    ``(pos, fed, samples)`` for the occupied slots."""
+    ``(pos, fed, samples)`` for the occupied slots; ``ref`` is the
+    configuration's reference module."""
+    body = ref.position_flops(s)
     total = 0.0
     for pos, fed, samples in slots:
-        total += fed * body_flops_per_position(s) \
+        total += fed * body \
             + attention_flops(s, pos, fed) + (head_flops(s) if samples else 0)
     return total
 

@@ -20,7 +20,8 @@ from repro.launch.mesh import device_mesh
 from repro.models import params as pm
 from repro.models.config import ModelConfig
 from repro.models.transformer import param_specs
-from repro.serve.engine import EngineConfig, EngineStats, ServingEngine
+from repro.serve.engine import (EngineConfig, EngineStats, SamplingParams,
+                                ServingEngine)
 
 # A CPU rehearsal serves the program's own tiny sibling of the model with
 # interpreted kernels and short sequences; it checks the harness's paths,
@@ -29,21 +30,34 @@ REHEARSAL_ENGINE = {"s_max": 256, "buckets": [1, 2, 4, 8],
                     "prefill_chunks": [16, 64], "n_kv_blocks": None,
                     "n_dense_slots": 8, "kernel_backend": "pallas-interpret"}
 
-SIZE_KEYS = ("family", "d_model", "n_layers", "n_heads", "n_kv_heads",
-             "d_ff", "vocab_size", "qkv_bias", "rope_theta", "d_inner",
-             "ssm_heads", "ssm_headdim", "ssm_state", "ssm_groups",
-             "conv_kernel")
+# The ``model`` block of a configuration file: every key that names a
+# ``ModelConfig`` field goes to the program; ``dtype`` sets its parameter
+# and compute types; every other key is the reference's alone and must be
+# listed in the file's ``reference_only``, so that a misspelt key raises.
+FIELDS = frozenset(f.name for f in dataclasses.fields(ModelConfig))
+
+
+def _frozen(v):
+    return tuple(_frozen(x) for x in v) if isinstance(v, list) else v
+
+
+def _block(conf: dict):
+    """``(program keys, reference-only keys)`` of the ``model`` block, lists
+    made tuples."""
+    m = {k: _frozen(v) for k, v in conf["model"].items() if k != "dtype"}
+    only = set(conf.get("reference_only", ()))
+    unknown = sorted(set(m) - FIELDS - only)
+    if unknown:
+        raise KeyError(f"{conf['name']}: model keys {unknown} name no "
+                       f"ModelConfig field and are not in reference_only")
+    return ({k: v for k, v in m.items() if k not in only},
+            {k: v for k, v in m.items() if k in only})
 
 
 def model_config(conf: dict, rehearse: bool) -> ModelConfig:
-    m = dict(conf["model"])
-    dt = jnp.dtype(m.pop("dtype"))
-    m.pop("rms_norm_eps")
-    m.pop("initializer_range", None)
-    if "layer_pattern" in m:
-        m["layer_pattern"] = tuple(tuple(p) for p in m["layer_pattern"])
+    dt = jnp.dtype(conf["model"]["dtype"])
     cfg = ModelConfig(name=conf["name"], param_dtype=dt, compute_dtype=dt,
-                      **m)
+                      **_block(conf)[0])
     if rehearse:
         from repro.configs.registry import reduced
         cfg = reduced(cfg)
@@ -51,14 +65,20 @@ def model_config(conf: dict, rehearse: bool) -> ModelConfig:
 
 
 def sizes(cfg: ModelConfig, conf: dict, rehearse: bool = False) -> dict:
-    """The sizes the reference and the FLOP counts read: those of the model
-    actually served."""
-    s = {k: getattr(cfg, k) for k in SIZE_KEYS}
-    s.update(head_dim=cfg.hd(), rms_norm_eps=conf["model"]["rms_norm_eps"],
+    """What the reference, its layout and the FLOP counts read: every key
+    of the ``model`` block, the program's at the values actually served
+    (the tiny sibling's in a rehearsal), the reference-only ones as written
+    (a rehearsal takes the file's ``rehearsal`` values of them, such as
+    ``initializer_range``), with ``head_dim``, ``layer_pattern`` and
+    ``dtype``.  Every value is hashable."""
+    prog, only = _block(conf)
+    s = {k: _frozen(getattr(cfg, k)) for k in prog}
+    s.update(only)
+    if rehearse:
+        s.update({k: v for k, v in conf.get("rehearsal", {}).items()
+                  if k in only})
+    s.update(head_dim=cfg.hd(), layer_pattern=cfg.pattern(),
              dtype=jnp.dtype(cfg.param_dtype).name)
-    init = conf.get("rehearsal", {}) if rehearse else conf["model"]
-    if "initializer_range" in init:
-        s["initializer_range"] = init["initializer_range"]
     return s
 
 
@@ -137,6 +157,15 @@ def warm_up(eng: ServingEngine) -> None:
     stride = eng.engine_cfg.block_pos_stride
     prompt = [(7 * i) % eng.cfg.vocab_size for i in range(3 * stride + 5)]
     eng.submit(prompt)
+    eng.drain()
+    eng.stats = EngineStats()
+
+
+def prime(eng: ServingEngine, prompts: list) -> None:
+    """Serve each prompt for one token, so that the prefix cache holds its
+    pages when the window opens; counters start from zero again."""
+    for p in prompts:
+        eng.submit(p, SamplingParams(max_tokens=1))
     eng.drain()
     eng.stats = EngineStats()
 

@@ -57,6 +57,22 @@ def shapes(s):
     }
 
 
+def layer_params(s) -> int:
+    """Weights of one layer's matmuls: the input and output projections."""
+    D, di, H = s["d_model"], s["d_inner"], s["ssm_heads"]
+    GN = s["ssm_groups"] * s["ssm_state"]
+    return D * (2 * di + 2 * GN + H) + di * D
+
+
+def position_flops(s) -> int:
+    """FLOPs of one position through every layer: 2 per weight, the
+    depthwise convolution, and the recurrence (state update and
+    read-out)."""
+    H, P, N = s["ssm_heads"], s["ssm_headdim"], s["ssm_state"]
+    conv = 2 * s["conv_kernel"] * (s["d_inner"] + 2 * s["ssm_groups"] * N)
+    return s["n_layers"] * (2 * layer_params(s) + conv + 4 * H * N * P)
+
+
 F32_LEAVES = ("final_norm", "norm", "dt_bias", "A_log", "D", "gate_norm")
 
 

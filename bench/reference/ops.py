@@ -42,6 +42,7 @@ def rms_norm(x, scale, eps):
     return x * inv * scale.astype(jnp.float32)
 
 
-def logits_rows(hidden, embed, mode: str = "f32"):
-    """Tied output head: ``hidden (R, D) @ embed (V, D)^T``."""
-    return mm(hidden, embed.T, mode)
+def logits_rows(hidden, head, mode: str = "f32"):
+    """Output head ``(V, D)`` (the embedding, where tied):
+    ``hidden (R, D) @ head^T``."""
+    return mm(hidden, head.T, mode)

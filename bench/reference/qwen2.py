@@ -38,6 +38,19 @@ def shapes(s):
 NORMS = ("final_norm", "norm1", "norm2")
 
 
+def layer_params(s) -> int:
+    """Weights of one layer's matmuls: Q, K, V, O and the SwiGLU MLP."""
+    D, F, hd = s["d_model"], s["d_ff"], s["head_dim"]
+    nq, nk = s["n_heads"] * hd, s["n_kv_heads"] * hd
+    return D * nq + 2 * D * nk + nq * D + 3 * D * F
+
+
+def position_flops(s) -> int:
+    """Matmul FLOPs of one position through every layer (2 per weight);
+    attention over the context is counted apart."""
+    return s["n_layers"] * 2 * layer_params(s)
+
+
 def init_weights(s, key):
     """Seeded weights: norm scales float32, everything else in ``s["dtype"]``."""
     dt = jnp.dtype(s["dtype"])

@@ -38,10 +38,6 @@ ROOT = os.path.dirname(BENCH)
 sys.path[:0] = [BENCH, os.path.join(ROOT, "src")]
 CACHE_DIR = os.path.join(ROOT, ".jax_cache")
 
-# what a CPU rehearsal offers instead of the mix's lengths and load
-REHEARSAL_TRAFFIC = {"prompt": {"dist": "uniform", "min": 20, "max": 60},
-                     "output": {"dist": "uniform", "min": 8, "max": 24},
-                     "rate_per_s": 20.0, "requests": 12}
 FINISH_S = 60.0     # how long a first token due in the window may take
 
 
@@ -109,8 +105,7 @@ def setup(args, patch=None):
     conf = files.config(cell["config"])
     traffic = dict(files.traffic(cell["traffic"]))
     if args.rehearse:
-        traffic.update({k: v for k, v in REHEARSAL_TRAFFIC.items()
-                        if k in traffic or k in ("prompt", "output")})
+        traffic.update(traffic.get("rehearsal", {}))
     cfg = program.model_config(conf, args.rehearse)
     s = program.sizes(cfg, conf, args.rehearse)
     params = program.make_weights(conf, cfg, s, program.mesh_for(chips),
@@ -119,9 +114,11 @@ def setup(args, patch=None):
     if patch is not None:
         patch(eng)
     program.warm_up(eng)
-    log = program.LaunchLog(eng) if args.trace else None
     gen = files.module("traffic", traffic["generator"])
     specs = gen.make(traffic, args.seed, cfg.vocab_size, args.seconds)
+    if hasattr(gen, "warm"):        # prompts the cache holds before the window
+        program.prime(eng, gen.warm(traffic, specs))
+    log = program.LaunchLog(eng) if args.trace else None
     next_turn = functools.partial(gen.next_turn, traffic) \
         if hasattr(gen, "next_turn") else None
     return types.SimpleNamespace(cell=cell, conf=conf, traffic=traffic,
@@ -257,6 +254,7 @@ def main(argv=None, patch=None) -> int:
         t0=w.t0, t_end=w.t_end, t_stop=w.t_stop, seconds=args.seconds,
         setup_s=w.t0 - T_START, stats=w.stats, red=red,
         launches=st.log.launches if st.log else None, s=st.s,
+        ref=files.module("reference", st.conf["reference"]),
         peak=None if args.rehearse else flops.peak(dev["kind"]))
     metrics = {}
     for m in files.metrics_for(args.workload, bool(args.trace)):

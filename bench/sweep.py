@@ -9,9 +9,17 @@ A rate is sustained when neither the queue of requests that are due and
 have no first token nor the count of streams in flight (first token
 delivered, not finished) grows over the window (each one's mean over the
 last quarter is at most 1.5 times its mean over the second, plus two) and
-every request due in the window got its first token within it plus 30 s.  The
-knee is the highest sustained rate; ``--write`` sets the cell's traffic
-file to four fifths of it.  Runs on the chip, like ``run.py``.
+every request due in the window got its first token within it plus 30 s.
+``--warm-s`` leaves the window's first seconds out of the quarters: a mix of
+sessions fills with live sessions for about one session's lifetime, and
+its streams in flight grow over that time at any rate.  The knee is the
+highest sustained rate; ``--write`` sets the cell's traffic file to four
+fifths of it.  Each rate is offered with a seed of its own, after set-up's
+``warm`` prompts of that seed, as ``run.py`` does.  Runs on the chip, like
+``run.py``.
+
+    python3 bench/sweep.py --workload qwen2-0.5b.prefix \
+        --rates 0.2,0.3,0.4,0.5,0.6,0.8 --seconds 102 --warm-s 30
 """
 
 import argparse
@@ -27,13 +35,15 @@ sys.path[:0] = [BENCH, os.path.join(os.path.dirname(BENCH), "src")]
 import run  # noqa: E402
 
 
-async def offer(st, rate, seconds, seed):
-    from harness import drive, files
+async def offer(st, rate, seconds, seed, warm_s):
+    from harness import drive, files, program
     from harness.stats import quantile
     from repro.serve.service import GenerateService, ServiceConfig
     mix = dict(st.traffic, rate_per_s=rate)
     gen = files.module("traffic", mix["generator"])
     specs = gen.make(mix, seed, st.cfg.vocab_size, seconds)
+    if hasattr(gen, "warm"):
+        program.prime(st.eng, gen.warm(mix, specs))
     queue, inflight = [], []
 
     async def sample(t0, recs):
@@ -51,6 +61,8 @@ async def offer(st, rate, seconds, seed):
                                              "first_token", 30.0,
                                              extra=sample,
                                              next_turn=st.next_turn)
+    skip = int(warm_s / 0.5)
+    queue, inflight = queue[skip:], inflight[skip:]
     q = len(queue) // 4
     quarters = lambda v: (sum(v[q:2 * q]) / q, sum(v[3 * q:]) / len(v[3 * q:]))
     early, late = quarters(queue)
@@ -61,7 +73,7 @@ async def offer(st, rate, seconds, seed):
     out_tok = sum(1 for r in recs for t in r.times if t <= t_end)
     ok = late <= 1.5 * early + 2 and fly_late <= 1.5 * fly_early + 2 \
         and all(x is not None for x in ttft)
-    return {"rate_per_s": rate, "requests": len(recs),
+    return {"rate_per_s": rate, "requests": len(recs), "warm_s": warm_s,
             "sustained": ok, "queue_q2": early, "queue_q4": late,
             "inflight_q2": fly_early, "inflight_q4": fly_late,
             "itl_p95_s": quantile(itl, 0.95),
@@ -76,6 +88,9 @@ def main():
     ap.add_argument("--rates", required=True)
     ap.add_argument("--seconds", type=float, default=40.0)
     ap.add_argument("--seed", type=int, default=7)
+    ap.add_argument("--warm-s", type=float, default=0.0,
+                    help="seconds at the window's start that the growth "
+                         "test leaves out")
     ap.add_argument("--write", action="store_true")
     ap.add_argument("--rehearse", action="store_true",
                     help="CPU rehearsal at the model's tiny sibling")
@@ -88,8 +103,10 @@ def main():
                               str(args.seed), "--seconds",
                               str(args.seconds)] + extra))
     rows = []
-    for rate in [float(r) for r in args.rates.split(",")]:
-        rows.append(asyncio.run(offer(st, rate, args.seconds, args.seed)))
+    for i, rate in enumerate(float(r) for r in args.rates.split(",")):
+        # a seed of its own per rate: prompts the cache has not seen
+        rows.append(asyncio.run(offer(st, rate, args.seconds,
+                                      args.seed + i, args.warm_s)))
         print(json.dumps(rows[-1]), flush=True)
     knee = max([r["rate_per_s"] for r in rows if r["sustained"]],
                default=None)

@@ -10,7 +10,8 @@ import pytest
 import run
 
 
-@pytest.mark.parametrize("cell", ["qwen2-0.5b.chat", "mamba2-780m.offline"])
+@pytest.mark.parametrize("cell", ["qwen2-0.5b.chat", "qwen2-0.5b.prefix",
+                                  "mamba2-780m.offline"])
 def test_float8_control_fails_the_rehearsal_limit(cell, capsys):
     rc = run.main(["--workload", cell, "--seed", "4100000023",
                    "--seconds", "5", "--trace", "0", "--rehearse",

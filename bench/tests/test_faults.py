@@ -42,7 +42,8 @@ def _half(eng):
 
 
 CASES = [(cell, fault, fault is None)
-         for cell in ("qwen2-0.5b.chat", "mamba2-780m.offline")
+         for cell in ("qwen2-0.5b.chat", "qwen2-0.5b.prefix",
+                      "mamba2-780m.offline")
          for fault in (None, _token, _state, _half)]
 
 

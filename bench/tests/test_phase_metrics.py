@@ -15,7 +15,8 @@ READERS = ("schedule_ms_per_step", "operand_ms_per_step",
            "publish_ms_per_step", "batch_occupancy", "prefill_pad_share")
 
 
-@pytest.mark.parametrize("cell", ["qwen2-0.5b.chat", "mamba2-780m.offline"])
+@pytest.mark.parametrize("cell", ["qwen2-0.5b.chat", "qwen2-0.5b.prefix",
+                                  "mamba2-780m.offline"])
 def test_counter_metrics_read_a_number_in_the_rehearsal(cell, capsys):
     names = [m["name"] for m in files.metrics_for(cell, True)
              if m["name"].split(".")[0] in READERS]

@@ -17,6 +17,7 @@ A traffic file's ``traffic`` block gives its parameters:
 
 * ``order_seed``: when given, the order of the lengths and gaps comes
   from it and not from the run's seed.
+* ``rehearsal``: the values a CPU rehearsal takes instead (``run.py``).
 
 Every seed gets the same work: lengths are the distribution's stratified
 quantiles and Poisson gaps the exponential's, so the count of requests,
@@ -46,6 +47,17 @@ def _lengths(d: dict, n: int) -> np.ndarray:
     return np.clip(np.floor(v), d["min"], d["max"]).astype(int)
 
 
+def poisson_due(rate: float, seconds: float, order) -> np.ndarray:
+    """Due times of ``round(rate x seconds)`` arrivals (at least one): the
+    exponential's stratified quantiles as gaps, in the order ``order``
+    permutes them, scaled so that all of them fall inside the window."""
+    n = max(1, int(round(rate * seconds)))
+    u = (np.arange(n) + 0.5) / n
+    gaps = order.permutation(-np.log1p(-u) / rate)
+    due = np.cumsum(gaps)
+    return due * (seconds * n / (n + 1) / due[-1])
+
+
 def make(params: dict, seed: int, vocab: int, seconds: float) -> list:
     """Requests of one run: dicts with ``due`` (seconds from the window's
     start), ``prompt``, ``max_tokens``, ``temperature`` and ``seed``."""
@@ -53,12 +65,8 @@ def make(params: dict, seed: int, vocab: int, seconds: float) -> list:
     order = np.random.default_rng(params["order_seed"]) \
         if "order_seed" in params else rng
     if params["arrival"] == "poisson":
-        rate = params["rate_per_s"]
-        n = max(1, int(round(rate * seconds)))
-        u = (np.arange(n) + 0.5) / n
-        gaps = order.permutation(-np.log1p(-u) / rate)
-        due = np.cumsum(gaps)
-        due *= seconds * n / (n + 1) / due[-1]    # all n inside the window
+        due = poisson_due(params["rate_per_s"], seconds, order)
+        n = len(due)
     elif params["arrival"] == "backlog":
         n = params["requests"]
         due = np.zeros(n)
