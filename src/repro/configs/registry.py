@@ -15,7 +15,9 @@ from repro.models.config import ModelConfig
 
 
 def reduced(cfg: ModelConfig) -> ModelConfig:
-    """Smoke-test sibling: same family, topology and code paths, tiny dims."""
+    """Smoke-test sibling: same family, topology and code paths, tiny dims.
+    NoPE, the attention scale, the muP multipliers and the RMSNorm epsilon
+    are kept as they are."""
     plen = len(cfg.pattern())
     kw = dict(
         name=cfg.name + "-smoke",
@@ -32,6 +34,13 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
     if cfg.n_experts:
         kw.update(n_experts=16, top_k=min(cfg.top_k, 2), d_ff_expert=32,
                   capacity_factor=2.0)
+    if cfg.experts_held:
+        # a share of 3 of the 16 experts the router scores, a token
+        # picking up to 4 (granite's holds 9 of 72, a token picking 10)
+        kw.update(top_k=min(cfg.top_k, 4), experts_held=3,
+                  expert_first=min(cfg.expert_first, 16 - 3))
+    if cfg.d_ff_shared:
+        kw.update(d_ff_shared=48)
     if cfg.d_inner:
         kw.update(d_inner=128, ssm_heads=8, ssm_headdim=16,
                   ssm_state=16, ssm_groups=min(cfg.ssm_groups, 4),

@@ -30,6 +30,15 @@ class ModelConfig:
     mlp_bias: bool = False
     act: str = "swiglu"            # swiglu | gelu
     rope_theta: float = 1e6
+    rope: bool = True              # False: no position embedding (NoPE)
+    attn_scale: Optional[float] = None   # None: head_dim ** -0.5
+    rms_norm_eps: float = 1e-6
+    # muP multipliers: the embedding is multiplied by the first, every
+    # residual branch by the second before it is added, and the logits are
+    # divided by the third
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     causal: bool = True
     attn_block_kv: int = 1024
     # MoE
@@ -41,6 +50,16 @@ class ModelConfig:
     moe_aux_coef: float = 1e-2
     moe_z_coef: float = 1e-3
     moe_wire_dtype: str = "native"   # native | int8  (dispatch/combine a2a)
+    # One chip's share of an expert-parallel layer (serving): the router
+    # scores all ``n_experts`` and picks ``top_k`` by its float32 logits,
+    # gated by a softmax over those k; this PE computes the experts
+    # [expert_first, expert_first + experts_held) for every token routed to
+    # them, dropless, and what the absent experts would add is left to the
+    # chips that hold them.  0 = the grid-wide all_to_all layer
+    # (``n_experts // n_pes`` experts per PE, capacity-bounded).
+    experts_held: int = 0
+    expert_first: int = 0
+    d_ff_shared: int = 0           # shared SwiGLU expert width; 0 = none
     # SSM (Mamba2 / SSD)
     d_inner: int = 0
     ssm_heads: int = 0
@@ -106,6 +125,8 @@ class AttnStatic:
     qkv_bias: bool
     causal: bool
     attn_block_kv: int
+    rope: bool = True
+    scale: Optional[float] = None
 
 
 def attn_static(cfg: ModelConfig, r: int, causal: Optional[bool] = None
@@ -119,4 +140,6 @@ def attn_static(cfg: ModelConfig, r: int, causal: Optional[bool] = None
         qkv_bias=cfg.qkv_bias,
         causal=cfg.causal if causal is None else causal,
         attn_block_kv=cfg.attn_block_kv,
+        rope=cfg.rope,
+        scale=cfg.attn_scale,
     )

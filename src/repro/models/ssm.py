@@ -161,7 +161,8 @@ def mamba_block(pctx: ParallelContext, p: Dict, x: jax.Array, cfg,
     y = y + Dskip[None, None, :, None] * xh.astype(jnp.float32)
     y = y.reshape(B, S_loc, di_loc)
     y = y * jax.nn.silu(z.astype(jnp.float32))
-    y = rms_norm(pctx, y.astype(x.dtype), p["ssm_norm"])
+    y = rms_norm(pctx, y.astype(x.dtype), p["ssm_norm"],
+                 eps=cfg.rms_norm_eps)
     out = dense(pctx, y, p["wo"], kind="crot")   # back to the residual layout
     # Decode conv cache wants the PRE-conv raw tail; row q-1 holds the
     # sequence-final one (serve/prefill selects it when building the cache).
@@ -234,7 +235,8 @@ def mamba_chunk_step(pctx: ParallelContext, p: Dict, x: jax.Array,
     y = y.astype(jnp.float32) + Dskip[None, None, :, None] \
         * xh.astype(jnp.float32)
     y = y.reshape(B, L, di_loc) * jax.nn.silu(z.astype(jnp.float32))
-    y = rms_norm(pctx, y.astype(x.dtype), p["ssm_norm"])
+    y = rms_norm(pctx, y.astype(x.dtype), p["ssm_norm"],
+                 eps=cfg.rms_norm_eps)
     out = dense(pctx, y, p["wo"])
     return out, (new_conv_state, new_ssm)
 
@@ -282,6 +284,7 @@ def mamba_decode_step(pctx: ParallelContext, p: Dict, x: jax.Array,
     Dskip = col_slice(pctx, p["D"], n_loc=H_loc).astype(jnp.float32)
     y = y.astype(jnp.float32) + Dskip[None, :, None] * xh.astype(jnp.float32)
     y = y.reshape(B, 1, di_loc) * jax.nn.silu(z.astype(jnp.float32))
-    y = rms_norm(pctx, y.astype(x.dtype), p["ssm_norm"])
+    y = rms_norm(pctx, y.astype(x.dtype), p["ssm_norm"],
+                 eps=cfg.rms_norm_eps)
     out = dense(pctx, y, p["wo"])
     return out, (new_conv_state, new_ssm)

@@ -62,9 +62,9 @@ def _attn_specs(cfg: ModelConfig, q: int, r: int, groups: int,
 
 
 def _mlp_specs(cfg: ModelConfig, q: int, r: int, groups: int,
-               sk=True) -> Dict:
+               sk=True, width: Optional[int] = None) -> Dict:
     dt = cfg.param_dtype
-    D, F = cfg.d_model, cfg.d_ff
+    D, F = cfg.d_model, width or cfg.d_ff
     sk_in = True if sk == "opt" else sk
     sk_out = "crot" if sk == "opt" else sk
     s = dict(
@@ -80,21 +80,37 @@ def _mlp_specs(cfg: ModelConfig, q: int, r: int, groups: int,
     return s
 
 
-def _moe_specs(cfg: ModelConfig, n_pes: int, groups: int) -> Dict:
+def _moe_specs(cfg: ModelConfig, q: int, r: int, groups: int,
+               sk=True) -> Dict:
+    """Router and experts; a share layer (``experts_held``) keeps its held
+    experts whole on every PE, the grid-wide layer spreads ``n_experts``
+    over the flat PE space.  A shared expert is a SwiGLU MLP."""
     dt = cfg.param_dtype
     D, F, E = cfg.d_model, cfg.d_ff_expert, cfg.n_experts
-    assert E % n_pes == 0, (E, n_pes)
-    e_loc = E // n_pes
-    def flat(shape):
-        spec = pm.ParamSpec((n_pes,) + shape, dt, pm.P(pm.MODEL), fan_in=D,
-                            meta=(("layout", "expert_flat"),))
-        return pm._stack(spec, groups)
-    return dict(
-        router=pm.replicated((D, E), dtype=jnp.float32, init="normal",
+    n_pes = q * r
+    s = dict(router=pm.replicated((D, E), dtype=jnp.float32, init="normal",
+                                  fan_in=D, groups=groups))
+    if cfg.experts_held:
+        Eh = cfg.experts_held
+        assert 0 <= cfg.expert_first <= E - Eh, (cfg.expert_first, Eh, E)
+        s.update(
+            w1=pm.replicated((Eh, D, 2 * F), dtype=dt, init="normal",
                              fan_in=D, groups=groups),
-        w1=flat((e_loc, D, 2 * F)),
-        w2=flat((e_loc, F, D)),
-    )
+            w2=pm.replicated((Eh, F, D), dtype=dt, init="normal", fan_in=F,
+                             groups=groups))
+    else:
+        assert E % n_pes == 0, (E, n_pes)
+        e_loc = E // n_pes
+
+        def flat(shape):
+            spec = pm.ParamSpec((n_pes,) + shape, dt, pm.P(pm.MODEL),
+                                fan_in=D, meta=(("layout", "expert_flat"),))
+            return pm._stack(spec, groups)
+        s.update(w1=flat((e_loc, D, 2 * F)), w2=flat((e_loc, F, D)))
+    if cfg.d_ff_shared:
+        s["shared"] = _mlp_specs(cfg, q, r, groups, sk,
+                                 width=cfg.d_ff_shared)
+    return s
 
 
 def _mamba_specs(cfg: ModelConfig, q: int, r: int, groups: int,
@@ -153,7 +169,7 @@ def _layer_specs(cfg: ModelConfig, q: int, r: int, groups: int,
             entry["ffn"] = _mlp_specs(cfg, q, r, groups, sk)
             entry["norm2"] = _norm_specs(cfg, groups)
         elif ffn == "moe":
-            entry["ffn"] = _moe_specs(cfg, q * r, groups)
+            entry["ffn"] = _moe_specs(cfg, q, r, groups, sk)
             entry["norm2"] = _norm_specs(cfg, groups)
         elif ffn != "none":
             raise ValueError(ffn)
@@ -266,7 +282,7 @@ def lm_loss(pctx: ParallelContext, lm_head_blk: jax.Array, x: jax.Array,
 def _norm(pctx, cfg, p, x):
     if cfg.norm == "layernorm":
         return layer_norm(pctx, x, p["scale"], p["bias"])
-    return rms_norm(pctx, x, p["scale"])
+    return rms_norm(pctx, x, p["scale"], eps=cfg.rms_norm_eps)
 
 
 def mlp_apply(pctx: ParallelContext, cfg, p: Dict, x: jax.Array) -> jax.Array:
@@ -348,10 +364,26 @@ def stack_forward(pctx: ParallelContext, cfg: ModelConfig, layers_p: list,
 # Full model forward + loss.
 # ---------------------------------------------------------------------------
 
+# fields the serving step programs (repro.serve.decode) compute and the
+# training forward does not, with the values at which they do nothing
+_SERVED_ONLY = {"experts_held": 0, "d_ff_shared": 0, "rope": True,
+                "attn_scale": None, "embedding_multiplier": 1.0,
+                "residual_multiplier": 1.0, "logits_scaling": 1.0}
+
+
+def _check_trainable(cfg: ModelConfig) -> None:
+    off = sorted(k for k, v in _SERVED_ONLY.items() if getattr(cfg, k) != v)
+    if off:
+        raise NotImplementedError(
+            f"{cfg.name}: {off} are served by the engine's step programs; "
+            f"the training forward does not compute them")
+
+
 def forward(pctx: ParallelContext, cfg: ModelConfig, params: Dict,
             batch: Dict, collect_cache: bool = False):
     """batch: tokens (B, S) [+ labels; + frames/patches for encdec/vlm].
     Returns (x_final (B, S_loc, D_loc), aux, caches)."""
+    _check_trainable(cfg)
     cd = cfg.compute_dtype
     tokens = batch["tokens"]   # VLM: (B, P+S_text) with -1 at patch positions
     x = embed_tokens(pctx, params["embed"], tokens, cd)

@@ -26,6 +26,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -37,7 +38,7 @@ from repro.models.config import ModelConfig, attn_static
 from repro.models.layers import (ParallelContext, apply_rope, col_slice,
                                  dense, fused_dense, rms_norm_local,
                                  rope_tables)
-from repro.models.moe import moe_block
+from repro.models.moe import moe_block, moe_share
 from repro.models.ssm import mamba_chunk_step, mamba_decode_step
 from repro.models.transformer import (_norm, apply_layer, embed_tokens,
                                       forward, mlp_apply, param_specs)
@@ -222,7 +223,8 @@ def _attn_decode_batched(pctx, p, x, cfg, kc, vc, pos):
     if cfg.qk_norm:
         q = rms_norm_local(q, p["q_norm"])
         k = rms_norm_local(k, p["k_norm"])
-    q, k = _rope_decode(q, k, pos, hd, cfg.rope_theta)
+    if cfg.rope:
+        q, k = _rope_decode(q, k, pos, hd, cfg.rope_theta)
     kv_pos = jnp.arange(kc.shape[1])
     if jnp.ndim(pos) == 0:
         kc = lax.dynamic_update_slice_in_dim(kc, k.astype(kc.dtype), pos,
@@ -232,12 +234,13 @@ def _attn_decode_batched(pctx, p, x, cfg, kc, vc, pos):
         part = attention_partial(
             q.transpose(0, 2, 1, 3), kc.transpose(0, 2, 1, 3),
             vc.transpose(0, 2, 1, 3), kv_pos=kv_pos,
-            q_pos=jnp.reshape(pos, (1,)))
+            q_pos=jnp.reshape(pos, (1,)), scale=cfg.scale)
     else:
         kc, vc = _scatter_kv(kc, vc, k, v, pos)
         part = attention_partial(
             q.transpose(0, 2, 1, 3), kc.transpose(0, 2, 1, 3),
-            vc.transpose(0, 2, 1, 3), kv_pos=kv_pos, q_pos=pos[:, None])
+            vc.transpose(0, 2, 1, 3), kv_pos=kv_pos, q_pos=pos[:, None],
+            scale=cfg.scale)
     out = (part.acc / jnp.maximum(part.l, 1e-30)[..., None])
     out = out.transpose(0, 2, 1, 3).reshape(B, 1, hq_loc * hd)
     y = dense(pctx, out.astype(x.dtype), p["wo"])
@@ -261,7 +264,8 @@ def _attn_decode_longctx(pctx, p, x, cfg, kc, vc, pos, shard_offset,
     if cfg.qk_norm:
         q = rms_norm_local(q, p["q_norm"])
         k = rms_norm_local(k, p["k_norm"])
-    q, k = _rope_decode(q, k, pos, hd, cfg.rope_theta)
+    if cfg.rope:
+        q, k = _rope_decode(q, k, pos, hd, cfg.rope_theta)
     # write the new KV into its owner shard (masked dynamic update)
     S_loc = kc.shape[1]
     kv_pos = shard_offset + jnp.arange(S_loc)
@@ -277,13 +281,14 @@ def _attn_decode_longctx(pctx, p, x, cfg, kc, vc, pos, shard_offset,
         part = attention_partial(
             q.transpose(0, 2, 1, 3), kc.transpose(0, 2, 1, 3),
             vc.transpose(0, 2, 1, 3), kv_pos=kv_pos,
-            q_pos=jnp.reshape(pos, (1,)))
+            q_pos=jnp.reshape(pos, (1,)), scale=cfg.scale)
     else:
         mine = (pos >= shard_offset) & (pos < shard_offset + S_loc)
         kc, vc = _scatter_kv(kc, vc, k, v, pos - shard_offset, valid=mine)
         part = attention_partial(
             q.transpose(0, 2, 1, 3), kc.transpose(0, 2, 1, 3),
-            vc.transpose(0, 2, 1, 3), kv_pos=kv_pos, q_pos=pos[:, None])
+            vc.transpose(0, 2, 1, 3), kv_pos=kv_pos, q_pos=pos[:, None],
+            scale=cfg.scale)
 
     # reduce over grid ROWS (+ the data axes when the cache shards there):
     def reduce_max(t):
@@ -315,7 +320,7 @@ def _rows_pmax(grid):
 
 
 def _paged_partial(q, kc, vc, table, q_pos, kv_len, stride, row, qrows,
-                   backend):
+                   backend, scale=None):
     """Per-row paged-attention partials, backend-dispatched.
 
     ``backend="jnp"`` materializes the gathered per-slot K/V runs
@@ -332,14 +337,14 @@ def _paged_partial(q, kc, vc, table, q_pos, kv_len, stride, row, qrows,
                                       qrows=qrows)
         return attention_partial(
             q, kg.transpose(0, 2, 1, 3), vg.transpose(0, 2, 1, 3),
-            kv_pos=kv_pos, q_pos=q_pos)
+            kv_pos=kv_pos, q_pos=q_pos, scale=scale)
     _, interpret = resolve_kernel_backend(backend)
     if q_pos.ndim == 1:      # scalar-pos decode: shared across the batch
         q_pos = jnp.broadcast_to(q_pos[None, :],
                                  (q.shape[0], q_pos.shape[0]))
     return paged_attention(q, kc, vc, table, q_pos, kv_len, stride=stride,
-                           row=row, qrows=qrows, backend="pallas",
-                           interpret=interpret)
+                           row=row, qrows=qrows, scale=scale,
+                           backend="pallas", interpret=interpret)
 
 
 def _attn_decode_paged(pctx, p, x, cfg, kc, vc, pos, table, stride,
@@ -371,7 +376,8 @@ def _attn_decode_paged(pctx, p, x, cfg, kc, vc, pos, table, stride,
     if cfg.qk_norm:
         q = rms_norm_local(q, p["q_norm"])
         k = rms_norm_local(k, p["k_norm"])
-    q, k = _rope_decode(q, k, pos, hd, cfg.rope_theta)
+    if cfg.rope:
+        q, k = _rope_decode(q, k, pos, hd, cfg.rope_theta)
 
     posv = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
     n_loc = kc.shape[0]
@@ -389,7 +395,7 @@ def _attn_decode_paged(pctx, p, x, cfg, kc, vc, pos, table, stride,
     # an idle slot (table all -1) walks nothing
     kv_len = jnp.where(jnp.any(table >= 0, axis=1), posv + 1, 0)
     part = _paged_partial(q.transpose(0, 2, 1, 3), kc, vc, table, q_pos,
-                          kv_len, stride, i, qrows, backend)
+                          kv_len, stride, i, qrows, backend, scale=cfg.scale)
     out = combine_partials(part, _rows_pmax(grid), grid.psum_rows)
     out = out.transpose(0, 2, 1, 3).reshape(B, 1, hq_loc * hd)
     y = dense(pctx, out.astype(x.dtype), p["wo"])
@@ -426,9 +432,10 @@ def _attn_prefill_chunk_paged(pctx, p, x, cfg, kc, vc, pos, n_valid, table,
         q = rms_norm_local(q, p["q_norm"])
         k = rms_norm_local(k, p["k_norm"])
     pos2 = pos[:, None] + jnp.arange(L)[None, :]            # (B, L) global
-    cos, sin = rope_tables(pos2, hd, cfg.rope_theta)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if cfg.rope:
+        cos, sin = rope_tables(pos2, hd, cfg.rope_theta)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
 
     valid = jnp.arange(L)[None, :] < n_valid[:, None]       # (B, L)
     n_loc = kc.shape[0]
@@ -445,7 +452,8 @@ def _attn_prefill_chunk_paged(pctx, p, x, cfg, kc, vc, pos, n_valid, table,
     vc = vc.at[li_w, off_w].set(v.astype(vc.dtype), mode="drop")
 
     part = _paged_partial(q.transpose(0, 2, 1, 3), kc, vc, table, pos2,
-                          pos + n_valid, stride, i, qrows, backend)
+                          pos + n_valid, stride, i, qrows, backend,
+                          scale=cfg.scale)
     out = combine_partials(part, _rows_pmax(grid), grid.psum_rows)
     out = out.transpose(0, 2, 1, 3).reshape(B, L, hq_loc * hd)
     y = dense(pctx, out.astype(x.dtype), p["wo"])
@@ -496,10 +504,21 @@ def _cross_decode(pctx, p, x, cfg, ck, cv):
     return dense(pctx, out.astype(x.dtype), p["wo"])
 
 
+def _residual(cfg, x, h):
+    """Add a residual branch, scaled by ``residual_multiplier``."""
+    m = cfg.residual_multiplier
+    return x + (h if m == 1.0 else h * m)
+
+
 def _decode_layer(pctx, cfg, mixer, ffn, p, x, cache, pos, shard_offset, mode,
                   table=None, paged=None, n_valid=None, slots=None,
-                  backend="jnp"):
+                  backend="jnp", live=None):
+    """One (mixer, ffn) layer of a step program -> ``(x, new_cache,
+    counts)``; ``counts`` is the share layer's rows per held expert and
+    positions routed (:func:`repro.models.moe.moe_share`), else None.
+    ``live`` (B, L) marks the positions that feed a token."""
     ast = attn_static(cfg, pctx.r) if mixer == "attn" else None
+    counts = None
     if mixer == "attn":
         h = _norm(pctx, cfg, p["norm1"], x)
         if paged is not None and n_valid is not None:
@@ -521,7 +540,7 @@ def _decode_layer(pctx, cfg, mixer, ffn, p, x, cache, pos, shard_offset, mode,
                                              cache["k"], cache["v"], pos,
                                              shard_offset,
                                              reduce_data=(mode == "longctx"))
-        x = x + h
+        x = _residual(cfg, x, h)
         new_cache = {"k": kc, "v": vc}
     elif slots is not None:
         # engine path (DenseSpec): per-slot state rides in a dense slot
@@ -536,14 +555,14 @@ def _decode_layer(pctx, cfg, mixer, ffn, p, x, cache, pos, shard_offset, mode,
         else:
             h, (conv, ssm) = mamba_decode_step(pctx, p["mixer"], h,
                                                (st["conv"], st["ssm"]), cfg)
-        x = x + h
+        x = _residual(cfg, x, h)
         new_cache = _dense_slot_scatter(cache, {"conv": conv, "ssm": ssm},
                                         slots)
     else:
         h = _norm(pctx, cfg, p["norm1"], x)
         h, (conv, ssm) = mamba_decode_step(pctx, p["mixer"], h,
                                            (cache["conv"], cache["ssm"]), cfg)
-        x = x + h
+        x = _residual(cfg, x, h)
         new_cache = {"conv": conv, "ssm": ssm}
     if "cross" in p:
         h = _norm(pctx, cfg, p["norm_cross"], x)
@@ -553,12 +572,99 @@ def _decode_layer(pctx, cfg, mixer, ffn, p, x, cache, pos, shard_offset, mode,
                          cross_v=cache["cross_v"])
     if ffn == "mlp":
         h = _norm(pctx, cfg, p["norm2"], x)
-        x = x + mlp_apply(pctx, cfg, p["ffn"], h)
+        x = _residual(cfg, x, mlp_apply(pctx, cfg, p["ffn"], h))
     elif ffn == "moe":
         h = _norm(pctx, cfg, p["norm2"], x)
-        y, _ = moe_block(pctx, p["ffn"], h, cfg)
-        x = x + y
-    return x, new_cache
+        if cfg.experts_held:
+            pallas, interpret = resolve_kernel_backend(backend)
+            y, counts = moe_share(pctx, p["ffn"], h, cfg, live=live,
+                                  pallas=pallas, interpret=interpret)
+        else:
+            y, _ = moe_block(pctx, p["ffn"], h, cfg)
+        if "shared" in p["ffn"]:
+            y = y + mlp_apply(pctx, cfg, p["ffn"]["shared"], h)
+        x = _residual(cfg, x, y)
+    return x, new_cache, counts
+
+
+def _scan_layers(cfg, params_layers, local_cache, x, layer):
+    """Scan the layer groups: ``layer(mixer, ffn, p, x, cache)`` is
+    :func:`_decode_layer` with the step's operands bound.  Returns ``(x,
+    new_cache, counts)``, counts summed over the share layers (None
+    without any)."""
+    pattern = cfg.pattern()
+    held = n_count_columns(cfg) > 0
+    unrolled = cfg.n_groups() == 1
+
+    def group_body(carry, xs):
+        x, cnt = carry if held else (carry, None)
+        group_params, group_cache = xs
+        new_caches = []
+        for posn, (mixer, ffn) in enumerate(pattern):
+            cache = group_cache[posn]
+            if unrolled and new_caches:
+                # unrolled, nothing orders the layers' state traffic: a
+                # barrier makes each layer read its state after the layer
+                # before has run and written its own, so that one layer's
+                # gathered and advanced state is alive at a time
+                x, cache, new_caches[-1] = lax.optimization_barrier(
+                    (x, cache, new_caches[-1]))
+            x, nc, c = layer(mixer, ffn, group_params[posn], x, cache)
+            if c is not None:
+                cnt = cnt + c
+            new_caches.append(nc)
+        return ((x, cnt) if held else x), new_caches
+
+    init = (x, jnp.zeros((n_count_columns(cfg),), jnp.int32)) if held else x
+    if cfg.n_groups() == 1:
+        # one repeat of the pattern: a scan of length one would slice a
+        # copy of every layer's weights and state out of its operands
+        first = lambda t: jax.tree.map(lambda a: a[0], t)
+        out, new_cache = group_body(init, first((params_layers,
+                                                 local_cache)))
+        new_cache = jax.tree.map(lambda a: a[None], new_cache)
+    else:
+        out, new_cache = lax.scan(group_body, init,
+                                  (params_layers, local_cache))
+    x, cnt = out if held else (out, None)
+    return x, new_cache, cnt
+
+
+def n_count_columns(cfg: ModelConfig) -> int:
+    """Columns that a share expert layer's counts add past the stored
+    vocabulary of every step's logits (:func:`_step_logits`); 0 for a
+    model without one."""
+    if cfg.experts_held and any(f == "moe" for _, f in cfg.pattern()):
+        return cfg.experts_held + 2
+    return 0
+
+
+def read_expert_counts(cfg: ModelConfig,
+                       logits) -> Optional[Tuple[int, int, int]]:
+    """A step's share-layer counts on the host, each summed over its MoE
+    layers: ``(rows the held experts computed, the busiest held expert's
+    rows in each layer, positions routed)``; None for a model without a
+    share layer.  The only reader of the columns :func:`_step_logits`
+    appends."""
+    n = n_count_columns(cfg)
+    if not n:
+        return None
+    c = np.asarray(logits[0, 0, -n:]).astype(np.int64)
+    return int(c[:-2].sum()), int(c[-2]), int(c[-1])
+
+
+def _step_logits(cfg, logits, counts):
+    """Divide by ``logits_scaling``; a share layer's counts ride as trailing
+    columns past the padded vocabulary (every row and position alike), so
+    a caller that cuts the logits at the vocabulary reads what it read
+    without them, and :func:`read_expert_counts` reads them."""
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    if counts is None:
+        return logits
+    c = jnp.broadcast_to(counts.astype(logits.dtype),
+                         logits.shape[:-1] + counts.shape)
+    return jnp.concatenate([logits, c], axis=-1)
 
 
 def _embed_decode(pctx, embed_blk, tokens, mode, compute_dtype):
@@ -578,6 +684,13 @@ def _embed_decode(pctx, embed_blk, tokens, mode, compute_dtype):
         return grid.reduce_scatter_rows(part, axis=0)[:, None, :]
     out = grid.psum_rows(part)                  # gemv/longctx: repl. rows
     return out if tokens.ndim == 2 else out[:, None, :]
+
+
+def _embed(cfg, pctx, embed_blk, tokens, mode):
+    """The embedding, multiplied by ``embedding_multiplier``."""
+    x = _embed_decode(pctx, embed_blk, tokens, mode, cfg.compute_dtype)
+    m = cfg.embedding_multiplier
+    return x if m == 1.0 else x * m
 
 
 def _last_logits(pctx, lm_head_blk, x, gather_rows: bool):
@@ -646,7 +759,6 @@ def make_decode_body(cfg: ModelConfig, mesh: Mesh, plan: MeshPlan, *,
                         preskew=pctx.preskewed)
     q, r = plan.grid_q, plan.grid_r
     dshards = plan.data_size * (plan.pod_size if plan.has_pod else 1)
-    pattern = cfg.pattern()
 
     if per_slot and mode == "longctx":
         raise NotImplementedError(
@@ -676,8 +788,7 @@ def make_decode_body(cfg: ModelConfig, mesh: Mesh, plan: MeshPlan, *,
             reset = extra[0]
         grid = pctx.grid
         i, _ = grid.my_coords()
-        x = _embed_decode(pctx, params["embed"], tokens, mode,
-                          cfg.compute_dtype)
+        x = _embed(cfg, pctx, params["embed"], tokens, mode)
         if per_slot and mode == "batched":
             # the embed reduce-scatter gave row i batch chunk i; slice the
             # per-slot operands to match
@@ -696,18 +807,16 @@ def make_decode_body(cfg: ModelConfig, mesh: Mesh, plan: MeshPlan, *,
         else:
             shard_offset = 0
 
-        def group_body(carry, xs):
-            x = carry
-            group_params, group_cache = xs
-            new_caches = []
-            for posn, (mixer, ffn) in enumerate(pattern):
-                x, nc = _decode_layer(pctx, cfg, mixer, ffn,
-                                      group_params[posn], x,
-                                      group_cache[posn], pos, shard_offset,
-                                      mode, table=table, paged=paged,
-                                      slots=slots, backend=kernel_backend)
-                new_caches.append(nc)
-            return x, new_caches
+        # an idle lane (no table page, no dense slot) routes to no expert
+        live = None
+        if slots is not None:
+            live = (slots >= 0)[:, None]
+        elif table is not None:
+            live = jnp.any(table >= 0, axis=1)[:, None]
+        layer = partial(_decode_layer, pctx, cfg, pos=pos,
+                        shard_offset=shard_offset, mode=mode, table=table,
+                        paged=paged, slots=slots, backend=kernel_backend,
+                        live=live)
 
         # strip the n_pes dim (shard_map gives local (G, 1, ...) leaves)
         local_cache = jax.tree.map(lambda c: c[:, 0], cache)
@@ -719,13 +828,13 @@ def make_decode_body(cfg: ModelConfig, mesh: Mesh, plan: MeshPlan, *,
                 sel = reset.reshape((1, -1) + (1,) * (c.ndim - 2)) > 0
                 return jnp.where(sel, jnp.zeros((), c.dtype), c)
             local_cache = jax.tree.map(_wipe, local_cache)
-        x, new_cache = lax.scan(group_body, x,
-                                (params["layers"], local_cache))
+        x, new_cache, counts = _scan_layers(cfg, params["layers"],
+                                            local_cache, x, layer)
         x = _norm(pctx, cfg, params["final_norm"], x)
         logits = _last_logits(pctx, params["lm_head"], x,
                               gather_rows=(mode == "batched"))
         new_cache = jax.tree.map(lambda c: c[:, None], new_cache)
-        return logits, new_cache
+        return _step_logits(cfg, logits, counts), new_cache
 
     pspecs = pm.param_pspecs(specs)
     cpspecs = sspecs.arena_pspecs() if sspecs is not None \
@@ -831,33 +940,22 @@ def make_prefill_chunk_body(cfg: ModelConfig, mesh: Mesh, plan: MeshPlan, *,
                      compute_dtype=cfg.compute_dtype)
     pctx = dataclasses.replace(pctx, act_layout="repl_rows", preskewed=False)
     specs = param_specs(cfg, plan.grid_q, plan.grid_r, preskew=False)
-    pattern = cfg.pattern()
     sspecs = layer_state_specs(cfg, plan, stride=paged.block_pos_stride)
 
     def body(params, cache, tokens, pos, n_valid, *extra):
         it = iter(extra)
         table = next(it) if sspecs.has_paged else None
         slots = next(it) if sspecs.has_dense else None
-        x = _embed_decode(pctx, params["embed"], tokens, "gemv",
-                          cfg.compute_dtype)
-
-        def group_body(carry, xs):
-            x = carry
-            group_params, group_cache = xs
-            new_caches = []
-            for posn, (mixer, ffn) in enumerate(pattern):
-                x, nc = _decode_layer(pctx, cfg, mixer, ffn,
-                                      group_params[posn], x,
-                                      group_cache[posn], pos, 0, "gemv",
-                                      table=table, paged=paged,
-                                      n_valid=n_valid, slots=slots,
-                                      backend=kernel_backend)
-                new_caches.append(nc)
-            return x, new_caches
-
+        x = _embed(cfg, pctx, params["embed"], tokens, "gemv")
+        # chunk padding and idle slots (n_valid 0) route to no expert
+        live = jnp.arange(tokens.shape[1])[None, :] < n_valid[:, None]
+        layer = partial(_decode_layer, pctx, cfg, pos=pos, shard_offset=0,
+                        mode="gemv", table=table, paged=paged,
+                        n_valid=n_valid, slots=slots, backend=kernel_backend,
+                        live=live)
         local_cache = jax.tree.map(lambda c: c[:, 0], cache)
-        x, new_cache = lax.scan(group_body, x,
-                                (params["layers"], local_cache))
+        x, new_cache, counts = _scan_layers(cfg, params["layers"],
+                                            local_cache, x, layer)
         if not all_logits:
             # extract each slot's last VALID chunk position before the final
             # norm + lm_head (both are pointwise over positions, so the
@@ -868,7 +966,7 @@ def make_prefill_chunk_body(cfg: ModelConfig, mesh: Mesh, plan: MeshPlan, *,
         x = _norm(pctx, cfg, params["final_norm"], x)
         logits = _last_logits(pctx, params["lm_head"], x, gather_rows=False)
         new_cache = jax.tree.map(lambda c: c[:, None], new_cache)
-        return logits, new_cache
+        return _step_logits(cfg, logits, counts), new_cache
 
     pspecs = pm.param_pspecs(specs)
     cpspecs = sspecs.arena_pspecs()

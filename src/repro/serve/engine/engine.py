@@ -53,7 +53,7 @@ from repro.core.hybrid import CommandQueue, HybridKernel
 from repro.kernels import check_kernel_backend, default_kernel_backend
 from repro.models import params as pm
 from repro.serve.decode import (PagedKV, make_decode_body,
-                                make_prefill_chunk_body)
+                                make_prefill_chunk_body, read_expert_counts)
 from repro.serve.engine.block_cache import BlockPool, block_layout
 from repro.serve.engine.request import Request, RequestState, SamplingParams
 from repro.serve.engine.scheduler import (ScheduledStep, Scheduler,
@@ -166,6 +166,11 @@ class EngineStats:
     # paged-attention walk per launch of a paged model (0 without pages)
     kv_pages_walked: int = 0              # ceil(kv_len / stride), occupied slots
     kv_pages_listed: int = 0              # bucket x table width
+    # a share expert layer's work per launch (0 without one), read from
+    # the counts the step program returns beside its logits
+    moe_rows: int = 0                     # rows the held experts computed
+    moe_rows_max: int = 0                 # each layer's busiest held expert's rows
+    moe_positions: int = 0                # positions routed x MoE layers
 
     @property
     def prefix_hit_rate(self) -> float:
@@ -580,7 +585,14 @@ class ServingEngine:
             sl = logits[:, pos, :self.cfg.vocab_size]
             sl.block_until_ready()
         with phase(self.stats, "fetch"):
-            return np.asarray(sl)
+            rows = np.asarray(sl)
+            moe = read_expert_counts(self.cfg, logits)
+            if moe is not None:
+                st = self.stats
+                st.moe_rows += moe[0]
+                st.moe_rows_max += moe[1]
+                st.moe_positions += moe[2]
+            return rows
 
     def _commit(self, sd: ScheduledStep, rows: np.ndarray, fed,
                 skip=frozenset()) -> None:

@@ -92,3 +92,60 @@ def test_ssd_kernels_compile_for_v5e(one_chip, L):
         ((B, nc, H, L, P), jnp.bfloat16), ((B, S, G, N), jnp.bfloat16),
         ((B, nc, H, 1, L), jnp.float32), ((B, nc, H, N, P), jnp.float32),
         sharding=one_chip, kernel="ssd_apply")
+
+
+# granite-4.0-h-small on one chip: NoPE attention of 32 query heads over 8
+# kv heads of width 128, scored at its published scale (1/128, not
+# 128 ** -0.5), over the offline cell's 8192-page arena (s_max 2048).
+G_HQ, G_KVH, G_HD, G_T = 32, 8, 128, 128
+
+
+@pytest.mark.parametrize("L", [1, 256], ids=["decode", "prefill"])
+def test_granite_paged_attention_compiles_for_v5e(one_chip, L):
+    B = 64
+    _compile(
+        lambda q, kc, vc, lidx, own, q0, kv_len: paged_attention_pallas(
+            q, kc, vc, lidx, own, q0, kv_len, stride=STRIDE,
+            scale=0.0078125),
+        ((B, G_HQ, L, G_HD), jnp.bfloat16),
+        ((N_PAGES, STRIDE, G_KVH, G_HD), jnp.bfloat16),
+        ((N_PAGES, STRIDE, G_KVH, G_HD), jnp.bfloat16),
+        ((B, G_T), jnp.int32), ((B, G_T), jnp.int32), ((B,), jnp.int32),
+        ((B,), jnp.int32), sharding=one_chip, kernel="paged_attention")
+
+
+# its share of an expert layer: 9 held experts of width 768 over d_model
+# 4096, the rows of a 64-slot decode step (64 x min(10, 9), rounded to the
+# kernel's row tile) through both grouped matmuls of the SwiGLU experts.
+def test_grouped_expert_gemm_compiles_for_v5e(one_chip):
+    from repro.models.moe import expert_ffn
+    _compile(
+        lambda xs, w1, w2, gs: expert_ffn(xs, w1, w2, gs, pallas=True),
+        ((640, 4096), jnp.bfloat16), ((9, 4096, 2 * 768), jnp.bfloat16),
+        ((9, 768, 4096), jnp.bfloat16), ((9,), jnp.int32),
+        sharding=one_chip, kernel="gmm")
+
+
+# two of those layers back to back: left free, XLA prefetches the second
+# layer's gate/up weights into VMEM ahead of its kernel (the device time of
+# the kernel then leaves out their reads); held in HBM, every weight reaches
+# its kernel from HBM and the kernel streams it itself.
+def test_expert_weights_reach_the_grouped_gemm_from_hbm(one_chip):
+    import re
+
+    from repro.models.moe import expert_ffn
+
+    def two_layers(xs, w1a, w2a, w1b, w2b, gs):
+        y = expert_ffn(xs, w1a, w2a, gs, pallas=True)
+        return expert_ffn(jnp.tanh(y) * xs, w1b, w2b, gs, pallas=True)
+
+    w1, w2 = ((9, 4096, 2 * 768), jnp.bfloat16), ((9, 768, 4096), jnp.bfloat16)
+    text = _compile(two_layers, ((640, 4096), jnp.bfloat16), w1, w2, w1, w2,
+                    ((9,), jnp.int32), sharding=one_chip, kernel="gmm")
+    defs = dict(re.findall(r"^\s*(%[\w.-]+) = (.*)$", text, re.M))
+    weights = [ops.split(", ")[-1].split("*/")[-1] for ops in re.findall(
+        r"%gmm(?:\.\d+)? = .*?custom-call\(([^)]*)\)", text)]
+    assert len(weights) == 4
+    for w in weights:
+        shape = defs[w].split(" ", 1)[0]
+        assert "S(1)" not in shape, (w, defs[w][:120])
